@@ -154,11 +154,11 @@ def source_location(eqn) -> Optional[str]:
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is None:
             # fall back to the innermost frame (library code) rather than
             # dropping provenance entirely
-            frames = list(source_info_util.user_frames(eqn.source_info))
+            frames = list(source_info_util.user_frames(eqn.source_info.traceback))
             frame = frames[0] if frames else None
         if frame is None:
             return None

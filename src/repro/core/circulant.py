@@ -28,7 +28,7 @@ Four forward implementations, selectable per layer (``impl=``):
                  matmul against precomputed real cos/sin bases → runs on the
                  MXU. Frequency contraction is a per-bin complex GEMM.
   * ``pallas`` — fused Pallas TPU kernel (see repro.kernels.block_circulant);
-                 falls back to interpret mode off-TPU.
+                 the Pallas interpreter runs it only on the CPU backend.
 
 All paths share the parameterization: the *time-domain* block table
 ``w ∈ R^{p×q×k}`` is the trainable parameter (so standard optimizers apply);

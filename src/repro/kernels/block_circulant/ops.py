@@ -2,7 +2,8 @@
 
 ``block_circulant_matmul(x, w)``: x (..., q·k) × blocks w (p, q, k) -> (..., p·k)
 
-* forward  — Pallas kernel (frequency-domain fused; interpret mode on CPU),
+* forward  — Pallas kernel (frequency-domain fused; interpret mode only on
+  the CPU backend, see :func:`resolve_interpret`),
   with an optional **fused epilogue** (bias add + activation) executed inside
   the kernel's final-q writeback, and an optional **frozen frequency-weight
   path** (``w_freq=(wr, wi)``) that skips the per-call ``rfft(w)`` entirely —
@@ -40,7 +41,6 @@ kernel launch (C-LSTM's fused gate dataflow).
 from __future__ import annotations
 
 import functools
-import os
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -62,6 +62,7 @@ __all__ = [
     "freq_weights_trace_count",
     "outer_dot_shapes",
     "count_pallas_launches",
+    "resolve_interpret",
 ]
 
 
@@ -101,19 +102,22 @@ def count_pallas_launches(jaxpr) -> int:
                if eqn.primitive.name == "pallas_call")
 
 
-def _force_interpret() -> bool:
-    """``REPRO_INTERPRET=1`` forces Pallas interpret mode even on TPU (the
-    CI matrix toggles this); any other value defers to platform detection."""
-    return os.environ.get("REPRO_INTERPRET", "") == "1"
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas interpret mode, decided by the default backend alone.
 
-
-def _on_tpu() -> bool:
-    if _force_interpret():
-        return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except (RuntimeError, IndexError):  # pragma: no cover - no backend
-        return False
+    ``None`` means interpret exactly when the default backend is the CPU.
+    On any other backend the kernel is compiled: asking for interpret mode
+    there is an error, never a silent slow path. Tests that compile for a
+    described TPU from the CPU pass ``interpret=False`` explicitly.
+    """
+    on_cpu = jax.default_backend() == "cpu"
+    if interpret is None:
+        return on_cpu
+    if interpret and not on_cpu:
+        raise ValueError(
+            f"interpret=True on the {jax.default_backend()!r} backend: the "
+            "Pallas interpreter runs only on the CPU backend")
+    return bool(interpret)
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -178,7 +182,7 @@ def _run_kernel(x2d: jax.Array, wr: jax.Array, wi: jax.Array,
     wr = _pad_to(_pad_to(wr, 0, pt), 1, qt)
     wi = _pad_to(_pad_to(wi, 0, pt), 1, qt)
     if w_scale is not None:
-        w_scale = _pad_to(_pad_to(w_scale, 0, pt), 1, qt)
+        w_scale = _pad_to(_pad_to(w_scale, 0, pt), 1, qt)[..., None]
     if wr.shape[1] != Q:                 # q padded -> pad x block dim to match
         xp = _pad_to(
             xp.reshape(xp.shape[0], Q, k), 1, qt
@@ -409,8 +413,7 @@ def block_circulant_matmul(
     the frozen tables as int8 with per-block symmetric scales, dequantized
     inside the kernel (inference-only: no VJP on the quantized path).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     if w_scale is not None and w_freq is None:
         raise ValueError("w_scale only applies to frozen w_freq tables")
     if w_freq is not None:
@@ -433,13 +436,13 @@ def block_circulant_matmul(
     x2d = x.reshape(-1, x.shape[-1])
     b2d = _as_bias2d(bias)
     if w_freq is not None and w_scale is not None:
-        y = _bc_freq_quant2d(bool(interpret), activation, int(k), int(p),
+        y = _bc_freq_quant2d(interpret, activation, int(k), int(p),
                              tiles, x2d, wr, wi, w_scale, b2d)
     elif w_freq is not None:
-        y = _bc_freq2d(bool(interpret), activation, int(k), int(p),
+        y = _bc_freq2d(interpret, activation, int(k), int(p),
                        tiles, x2d, wr, wi, b2d)
     else:
-        y = _bc_matmul2d(bool(interpret), activation, x2d, w, b2d)
+        y = _bc_matmul2d(interpret, activation, x2d, w, b2d)
     return y.reshape(*lead, p * k)
 
 

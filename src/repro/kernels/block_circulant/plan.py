@@ -276,8 +276,7 @@ def build_plan(
     so they land on the scale floor and still contribute exact zeros).
     """
     _check_quantize(quantize)
-    if interpret is None:
-        interpret = not bc_ops._on_tpu()
+    interpret = bc_ops.resolve_interpret(interpret)
     p, q, k = w.shape
     geo = plan_geometry(p, q, k, "float32", b_hint)
     wr, wi = bc_ops.freq_weights(w)
@@ -291,7 +290,7 @@ def build_plan(
     return BCPlan(
         wr=wr, wi=wi, bias=b2d,
         k=k, p=p, q=q, pt=geo.pt, qt=geo.qt, splits=(p,),
-        activation=activation, interpret=bool(interpret), scale=scale,
+        activation=activation, interpret=interpret, scale=scale,
     )
 
 
@@ -311,8 +310,7 @@ def build_multi_plan(
     ``apply_multi`` splits the fused output back per projection.
     (``quantize`` commutes with the stacking — scales are per-block.)
     """
-    if interpret is None:
-        interpret = not bc_ops._on_tpu()
+    interpret = bc_ops.resolve_interpret(interpret)
     q, k = ws[0].shape[1], ws[0].shape[2]
     for w in ws:
         if w.shape[1:] != (q, k):

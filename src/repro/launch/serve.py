@@ -18,24 +18,31 @@ slots (bounded jit recompilation on both paths), and freezes the circulant
 frequency weights once at load — see repro.serve.engine for the serving
 model. ``--stream`` demos the open-ended submit()/step()/poll()/drain()
 API instead of the closed generate() call.
+
+The launcher exits non-zero when any request ends in a status other than
+FINISHED, unless deadlines, a bounded queue or tenants (whose SLO classes
+carry deadlines) were asked for, since those end requests on purpose.
 """
 
 from __future__ import annotations
 
 import argparse
+import sys
 import time
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
 from repro.configs.registry import ARCHS, get_config, get_smoke
 from repro.ft.checkpoint import latest_step, restore_checkpoint
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import build_model
 from repro.nn.module import init_params
 from repro.serve.engine import (Request, SamplingParams, Scheduler,
                                 ServeEngine, WaveEngine)
 from repro.serve.frontend import (SLO_CLASSES, AsyncFrontend, TenantConfig,
                                   TenantRejectedError)
-from repro.serve.guard import QueueFullError
+from repro.serve.guard import FINISHED, QueueFullError
 from repro.serve.runner import recurrent_mixer_names
 
 
@@ -112,7 +119,48 @@ def _resolve_arch(ap: argparse.ArgumentParser, name: str) -> str:
     return normalized
 
 
-def main():
+def load_params(model, ckpt_dir: str, seed: int):
+    """The newest checkpoint's params under ``ckpt_dir``, or params drawn
+    at random from ``seed`` when there is none."""
+    # one directory scan per load (latest_step used to run twice)
+    step = latest_step(ckpt_dir) if ckpt_dir else None
+    if step is None:
+        print("serving freshly initialized params (demo mode)")
+        return init_params(model.specs(), seed)
+    print(f"restored checkpoint step {step}")
+    return restore_checkpoint(ckpt_dir, step)["params"]
+
+
+def build_engine(cfg, *, batch: int, cache_len: int, seed: int = 0,
+                 ckpt_dir: str = "", **engine_kw) -> ServeEngine:
+    """The continuous engine for ``cfg``, as the launcher serves it."""
+    model = build_model(cfg)
+    return ServeEngine(model, cfg, load_params(model, ckpt_dir, seed),
+                       batch=batch, cache_len=cache_len, **engine_kw)
+
+
+def serve_requests(engine: ServeEngine, reqs: Sequence[Request]
+                   ) -> Tuple[List[List[int]], List[str]]:
+    """Serve ``reqs`` to completion; returns their tokens and terminal
+    statuses, in request order. A submit refused at the queue bound steps
+    the engine and retries, as ``ServeEngine.generate`` does."""
+    rids = []
+    for r in reqs:
+        while True:
+            try:
+                rids.append(engine.submit(r))
+                break
+            except QueueFullError:
+                engine.step()
+    while engine.step():
+        pass
+    # poll before drain: drain claims (forgets) the status
+    statuses = [engine.poll(rid).status for rid in rids]
+    done = engine.drain(rids)
+    return [done[rid] for rid in rids], statuses
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="",
                     help="registry model name (repro.configs.registry), "
@@ -202,22 +250,12 @@ def main():
                          "int8 with per-block scales (dequantized inside "
                          "the kernel); halves resident table bytes at "
                          "identical launch counts")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if bool(args.model) == bool(args.arch):
         ap.error("pass exactly one of --model / --arch (they are aliases)")
     arch = _resolve_arch(ap, args.model or args.arch)
     cfg = get_smoke(arch) if args.smoke else get_config(arch)
-    model = build_model(cfg)
-    # one directory scan per load (latest_step used to run twice)
-    step = latest_step(args.ckpt_dir) if args.ckpt_dir else None
-    if step is not None:
-        state = restore_checkpoint(args.ckpt_dir, step)
-        params = state["params"]
-        print(f"restored checkpoint step {step}")
-    else:
-        params = init_params(model.specs(), 0)
-        print("serving freshly initialized params (demo mode)")
 
     prompt_buckets = _parse_buckets(ap, args.prompt_buckets,
                                     "--prompt-buckets")
@@ -246,6 +284,7 @@ def main():
         ap.error("--snapshot-every has no effect without --snapshot-dir")
     if args.shed_policy != "reject" and max_queue is None:
         ap.error("--shed-policy has no effect without --max-queue")
+    enable_compile_cache()
     if args.engine == "wave":
         if args.temperature > 0 or args.top_k or args.stop_token:
             ap.error("--engine wave is a greedy-only baseline; "
@@ -278,13 +317,16 @@ def main():
                      f"{'/'.join(mix)} layers no pad-validity guarantee: "
                      f"use the continuous engine (pad-aware "
                      f"RecurrentRunner) or --batch 1")
-        engine = WaveEngine(model, cfg, params, batch=args.batch,
-                            cache_len=args.cache_len,
+        model = build_model(cfg)
+        engine = WaveEngine(model, cfg,
+                            load_params(model, args.ckpt_dir, args.seed),
+                            batch=args.batch, cache_len=args.cache_len,
                             quantize=args.quantize)
     else:
         try:
-            engine = ServeEngine(model, cfg, params, batch=args.batch,
-                                 cache_len=args.cache_len,
+            engine = build_engine(cfg, batch=args.batch,
+                                 cache_len=args.cache_len, seed=args.seed,
+                                 ckpt_dir=args.ckpt_dir,
                                  prompt_buckets=prompt_buckets,
                                  decode_buckets=decode_buckets,
                                  policy=policy,
@@ -388,15 +430,16 @@ def main():
             # poll before drain: drain claims (forgets) the requests, and
             # an EXPIRED/FAILED terminal should print as such rather than
             # masquerade as a short finish
-            for rid in rids:
-                v = engine.poll(rid)
-                if v.status != "FINISHED":
-                    print(f"req {rid}: {v.status}"
+            states = [engine.poll(rid) for rid in rids]
+            for v in states:
+                if v.status != FINISHED:
+                    print(f"req {v.req_id}: {v.status}"
                           + (f" ({v.error})" if v.error else ""))
             done = engine.drain(rids)
-            return [done[rid] for rid in rids]
+            return ([done[rid] for rid in rids],
+                    [v.status for v in states])
 
-        outs = asyncio.run(_serve())
+        outs, statuses = asyncio.run(_serve())
     elif args.stream:
         # open-ended serving: trickle submissions in while the engine steps,
         # poll for incremental tokens, then drain the stragglers. A submit
@@ -420,10 +463,16 @@ def main():
             v = engine.poll(rid)
             print(f"submitted req {rid} (prompt_len={r.prompt_len}); "
                   f"poll -> status={v.status} tokens={list(v.tokens)}")
+        while engine.step():
+            pass
+        statuses = [engine.poll(rid).status for rid in rids]
         done = engine.drain(rids)
         outs = [done[rid] for rid in rids]
-    else:
+    elif args.engine == "wave":
         outs = engine.generate(reqs)
+        statuses = [FINISHED] * len(outs)   # no lifecycle: all or raise
+    else:
+        outs, statuses = serve_requests(engine, reqs)
     dt = time.perf_counter() - t0
     for i, o in enumerate(outs):
         print(f"request {i}: {o}")
@@ -458,6 +507,13 @@ def main():
           f"decode compiles={engine.decode_compiles} "
           f"tokens/decode-step={engine.stats.tokens_per_decode_step:.2f}"
           f"{extra}")
+    # deadlines, a bounded queue and tenant SLO classes end requests on
+    # purpose; without them, anything but FINISHED is a failure
+    lossy = deadline_ms is not None or max_queue is not None or tenants
+    bad = [(i, st) for i, st in enumerate(statuses) if st != FINISHED]
+    if bad and not lossy:
+        sys.exit(f"{len(bad)} of {len(statuses)} requests did not finish: "
+                 f"{bad}")
 
 
 if __name__ == "__main__":

@@ -313,8 +313,7 @@ def test_prefix_cache_rejects_short_ring_caches():
 
 def test_donation_on_off_equivalence(lm):
     """donate_argnums is pure plumbing: outputs bit-identical with the
-    cache donated or copied, with and without the prefix cache (the
-    REPRO_INTERPRET CI matrix runs this file under interpret mode too)."""
+    cache donated or copied, with and without the prefix cache."""
     cfg, model, params = lm
     reqs = _mix(23, 6)
     d_on = ServeEngine(model, cfg, params, batch=2, cache_len=32)

@@ -74,3 +74,13 @@ def test_kernel_inside_jit_and_grad_pipeline():
     for _ in range(20):
         w = w - 0.1 * jax.grad(loss)(w)
     assert float(loss(w)) < float(l0)
+
+
+def test_interpret_mode_only_on_cpu_backend(monkeypatch):
+    from repro.kernels.block_circulant import ops
+    assert ops.resolve_interpret() is True            # CPU backend here
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_interpret() is False
+    assert ops.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret=True"):
+        ops.resolve_interpret(True)
