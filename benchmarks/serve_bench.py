@@ -282,7 +282,6 @@ def _run_tenants(n_requests, batch, cache_len, seed, json_path):
             "fairness_at_boundary": {"admitted": fair_at,
                                      "total": fair_total},
             "ttft_ms": {"p50": s.ttft_ms.p50, "p99": s.ttft_ms.p99},
-            "tok_ms": {"p50": s.tok_ms.p50, "p99": s.tok_ms.p99},
             "tenants": {t: ts.as_dict() for t, ts in s.tenants.items()},
             "contract": {
                 "streams_bit_identical": True,

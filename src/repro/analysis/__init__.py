@@ -15,9 +15,9 @@ demonstrate. This package turns those one-off assertions into a subsystem:
   (``count_pallas_launches``/``outer_dot_shapes``) are wrappers over it.
 * :mod:`repro.analysis.rules` — named declarative rules (``NoFFT``,
   ``NoWeightFFT``, ``NoDenseDotGeneral``, ``DenseFallbackDot``,
-  ``LaunchBudget``, ``NoWeightConcat``, ``QuantizedTableDtypes``,
-  ``DonatedInputsAliased``) that return :class:`Violation`\\ s, never bare
-  booleans.
+  ``LaunchBudget``, ``NoWeightConcat``, ``ScopedContractions``,
+  ``QuantizedTableDtypes``, ``DonatedInputsAliased``) that return
+  :class:`Violation`\\ s, never bare booleans.
 * :mod:`repro.analysis.contracts` — rules grouped into per-surface
   contracts (frozen-plan forward, train step, every serve prefill/decode
   bucket, int8 serve + launch parity). ``ServeEngine.audit()`` and
@@ -39,9 +39,10 @@ from repro.analysis.lint import lint_file, lint_paths
 from repro.analysis.rules import (DenseFallbackDot, DonatedInputsAliased,
                                   LaunchBudget, NoDenseDotGeneral, NoFFT,
                                   NoWeightConcat, NoWeightFFT,
-                                  QuantizedTableDtypes, Violation)
+                                  QuantizedTableDtypes, ScopedContractions,
+                                  Violation)
 from repro.analysis.walker import (collect_pure_vars, iter_eqns,
-                                   source_location)
+                                   iter_scoped_eqns, source_location)
 
 __all__ = [
     "Contract",
@@ -53,6 +54,7 @@ __all__ = [
     "DenseFallbackDot",
     "LaunchBudget",
     "NoWeightConcat",
+    "ScopedContractions",
     "QuantizedTableDtypes",
     "DonatedInputsAliased",
     "audit_config",
@@ -60,6 +62,7 @@ __all__ = [
     "run_contract",
     "collect_pure_vars",
     "iter_eqns",
+    "iter_scoped_eqns",
     "source_location",
     "lint_file",
     "lint_paths",

@@ -19,6 +19,10 @@ serve_decode[...]       (fused shapes); plus NoFFT when the config's impl is
                         ``paper``/``freq`` impls legitimately transform
                         *activations*, so only the weight side is
                         contractual); one surface per engine bucket.
+                        Decode surfaces of attention-only, expert-free
+                        models add ScopedContractions (every contraction
+                        under a ``DECODE_SCOPES`` name; MoE routing and
+                        recurrent mixers have no scope of their own).
 serve_params            QuantizedTableDtypes (engine's quantize mode).
 serve_donation          DonatedInputsAliased on the lowered decode/prefill
                         modules (engines built with ``donate=True``).
@@ -45,7 +49,8 @@ import numpy as np
 from repro.analysis.rules import (DenseFallbackDot, DonatedInputsAliased,
                                   LaunchBudget, NoDenseDotGeneral, NoFFT,
                                   NoWeightConcat, NoWeightFFT,
-                                  QuantizedTableDtypes, Violation)
+                                  QuantizedTableDtypes, ScopedContractions,
+                                  Violation)
 
 __all__ = [
     "Contract",
@@ -285,6 +290,17 @@ def _serve_rules(engine) -> Tuple[Any, ...]:
     return tuple(rules)
 
 
+def _decode_rules(engine) -> Tuple[Any, ...]:
+    """The decode surfaces' extra rules: the model step's named scopes,
+    for models whose every contraction one of them describes."""
+    from repro.serve.runner import recurrent_mixer_names
+
+    cfg = engine.cfg
+    if cfg.is_moe or recurrent_mixer_names(cfg):
+        return ()
+    return (ScopedContractions(),)
+
+
 def audit_engine(engine, traces=None) -> List[Violation]:
     """All single-engine serve contracts: every bucketed executable's trace
     rules, the frozen-table dtype contract for the engine's quantize mode,
@@ -296,9 +312,13 @@ def audit_engine(engine, traces=None) -> List[Violation]:
     if not engine.cfg.swm.enabled:
         return out                          # dense config: nothing to promise
     rules = _serve_rules(engine)
+    decode_rules = rules + _decode_rules(engine)
     traces = serve_trace_jaxprs(engine) if traces is None else traces
     for name, jp in traces:
-        out.extend(run_contract(Contract(name=name, rules=rules), jp))
+        surface_rules = (decode_rules if name.startswith("serve_decode")
+                         else rules)
+        out.extend(run_contract(Contract(name=name, rules=surface_rules),
+                                jp))
 
     for v in QuantizedTableDtypes(engine.quantize).check_params(
             engine.params):

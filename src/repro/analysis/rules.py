@@ -27,6 +27,9 @@ Jaxpr rules (``check(jaxpr)``):
 * :class:`NoWeightConcat` — no ``concatenate`` producing a stacked frozen
   table shape (fused QKV/LSTM-gate groups must be pre-concatenated by
   ``freeze_params``, never concatenated per-trace).
+* :class:`ScopedContractions` — every ``dot_general`` and ``pallas_call``
+  sits under one of the model step's ``jax.named_scope`` names
+  (:data:`DECODE_SCOPES`), which a profile groups device time by.
 
 Value rules (checked against non-jaxpr artifacts):
 
@@ -42,7 +45,7 @@ import dataclasses
 from typing import Iterable, List, Optional, Sequence, Set, Tuple
 
 from repro.analysis.walker import (collect_pure_vars, iter_eqns,
-                                   source_location)
+                                   iter_scoped_eqns, source_location)
 
 __all__ = [
     "Violation",
@@ -52,6 +55,8 @@ __all__ = [
     "DenseFallbackDot",
     "LaunchBudget",
     "NoWeightConcat",
+    "ScopedContractions",
+    "DECODE_SCOPES",
     "QuantizedTableDtypes",
     "DonatedInputsAliased",
 ]
@@ -282,6 +287,37 @@ class NoWeightConcat:
                 f"inside every cached executable",
                 eqn,
             ))
+        return out
+
+
+#: The model step's stable ``jax.named_scope`` names: ``circulant`` (the
+#: ``core.circulant`` projection entry points), ``attention``
+#: (``Attention.__call__``), ``kv_move`` (a runner's ``gather_state`` /
+#: ``place_state``) and ``head`` (the logits head). A device op's profiler
+#: name carries them as path segments; the innermost one names its layer.
+DECODE_SCOPES = ("circulant", "attention", "kv_move", "head")
+
+
+class ScopedContractions:
+    """Every ``dot_general`` and ``pallas_call`` sits under one of
+    :data:`DECODE_SCOPES`, at any depth. A refactor that drops a scope
+    would otherwise move its device time into a profile's unattributed
+    rest without a sound."""
+
+    name = "ScopedContractions"
+    PRIMITIVES = ("dot_general", "pallas_call")
+
+    def check(self, jaxpr) -> List[Violation]:
+        out = []
+        for eqn, scopes in iter_scoped_eqns(jaxpr):
+            if (eqn.primitive.name in self.PRIMITIVES
+                    and not set(DECODE_SCOPES).intersection(scopes)):
+                out.append(_flag(
+                    self.name,
+                    f"{eqn.primitive.name} under scopes {list(scopes)}, "
+                    f"none of {list(DECODE_SCOPES)}",
+                    eqn,
+                ))
         return out
 
 

@@ -12,7 +12,9 @@ a launch. Pass ``into_pallas=True`` to lift that boundary.
 
 ``source_location`` maps an equation back to the user frame that traced it
 (``file.py:line``), so rule violations point at code, not at a count
-mismatch.
+mismatch. ``iter_scoped_eqns`` walks the same way and yields each
+equation's ``jax.named_scope`` names, outermost first, with those of the
+equations that enclose it: a sub-jaxpr's own name stacks start empty.
 
 This module must stay dependency-free within ``repro`` — it is imported by
 ``kernels.block_circulant.ops`` (whose public probes are thin wrappers over
@@ -27,6 +29,7 @@ __all__ = [
     "as_jaxpr",
     "collect_pure_vars",
     "iter_eqns",
+    "iter_scoped_eqns",
     "iter_sub_jaxprs",
     "source_location",
 ]
@@ -63,15 +66,29 @@ def iter_eqns(jaxpr, *, into_pallas: bool = False) -> Iterator:
     ``pallas_call`` eqns are always yielded themselves; their kernel body is
     only descended into when ``into_pallas=True``.
     """
-    stack = [as_jaxpr(jaxpr)]
+    for eqn, _ in iter_scoped_eqns(jaxpr, into_pallas=into_pallas):
+        yield eqn
+
+
+def iter_scoped_eqns(jaxpr, *, into_pallas: bool = False) -> Iterator:
+    """``iter_eqns`` yielding ``(eqn, scopes)``: the ``jax.named_scope``
+    names in force where ``eqn`` was traced, outermost first, including
+    those of every equation whose sub-jaxpr holds it (transform entries
+    of a name stack, such as ``jvp``, are not scopes)."""
+    from jax._src.source_info_util import Scope
+
+    stack = [(as_jaxpr(jaxpr), ())]
     while stack:
-        jx = stack.pop()
+        jx, outer = stack.pop()
         for eqn in jx.eqns:
-            yield eqn
+            scopes = outer + tuple(e.name for e in
+                                   eqn.source_info.name_stack.stack
+                                   if isinstance(e, Scope))
+            yield eqn, scopes
             if eqn.primitive.name == "pallas_call" and not into_pallas:
                 continue
             for val in eqn.params.values():
-                stack.extend(iter_sub_jaxprs(val))
+                stack.extend((sub, scopes) for sub in iter_sub_jaxprs(val))
 
 
 def _is_literal(v) -> bool:

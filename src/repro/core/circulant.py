@@ -450,6 +450,7 @@ def _dft_pair_bwd(res, gs):
 _dft_pair_op.defvjp(_dft_pair_fwd, _dft_pair_bwd)
 
 
+@jax.named_scope("circulant")
 def block_circulant_apply_pair(x: jax.Array, w1: jax.Array, w2: jax.Array):
     """(y1, y2) = (BC(w1)·x, BC(w2)·x) with one shared forward DFT."""
     lead = x.shape[:-1]
@@ -464,6 +465,7 @@ def block_circulant_apply_pair(x: jax.Array, w1: jax.Array, w2: jax.Array):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("circulant")
 def block_circulant_apply(
     x: jax.Array,
     w: jax.Array,
@@ -514,6 +516,7 @@ def dequantize_freq_pair(wr: jax.Array, wi: jax.Array,
             dequantize_symmetric(wi, w_scale))
 
 
+@jax.named_scope("circulant")
 def block_circulant_apply_fused(
     x: jax.Array,
     w: Optional[jax.Array],
@@ -584,6 +587,7 @@ def split_outputs(y: jax.Array, splits, k: int):
     return outs
 
 
+@jax.named_scope("circulant")
 def block_circulant_apply_multi(
     x: jax.Array,
     ws,

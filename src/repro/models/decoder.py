@@ -336,6 +336,7 @@ class HybridDecoderLM:
             return params["embed"]["table"]
         return params["lm_head"]["w"].T
 
+    @jax.named_scope("head")
     def _logits(self, params, x):
         cfg = self.cfg
         emb = Embedding(cfg.vocab, cfg.d_model, dtype=cfg.param_dtype)

@@ -297,6 +297,7 @@ class Attention:
         return self.cfg.rope_theta_local if self.local else self.cfg.rope_theta
 
     # -- forward ---------------------------------------------------------
+    @jax.named_scope("attention")
     def __call__(
         self,
         params,

@@ -68,6 +68,7 @@ class Embedding:
             x = x * jnp.asarray(self.dim**0.5, x.dtype)
         return x
 
+    @jax.named_scope("head")
     def decode(self, params, x: jax.Array) -> jax.Array:
         """Tied logits head: (..., d) @ (vocab, d)^T -> f32 logits."""
         return jnp.einsum(

@@ -223,14 +223,17 @@ faults, and overload the normal operating regime. The engine's contract:
   admission, never the math.
 
 * **SLO instrumentation** — ``EngineStats.ttft_ms`` (submit → first
-  token) and ``tok_ms`` (inter-token gap) are streaming
-  :class:`LatencyHistogram` s over fixed log-spaced buckets: p50/p99
-  read in O(buckets), memory is constant, and ``snapshot()`` serializes
-  the bucket counts exactly — a restored engine reports the same
-  quantiles. The async front-end (``repro.serve.frontend``) maps
-  tenants to SLO *classes* (interactive/standard/batch) that default
-  ``deadline_ms`` and DRR weights, and enforces per-tenant token-bucket
-  admission upstream of the queue bound. ``QueueFullError`` carries
+  token) is a streaming :class:`LatencyHistogram` over fixed log-spaced
+  buckets: p50/p99 read in O(buckets), memory is constant, and
+  ``snapshot()`` serializes the bucket counts exactly — a restored engine
+  reports the same quantiles. Inter-token gaps are the client's to time.
+  The engine's phases are ``jax.profiler.TraceAnnotation`` spans
+  (``serve.step``, ``serve.admit``, ``serve.prefill.*``,
+  ``serve.decode.*``), recorded only while a profiler session runs.
+  The async front-end (``repro.serve.frontend``) maps tenants to SLO
+  *classes* (interactive/standard/batch) that default ``deadline_ms``
+  and DRR weights, and enforces per-tenant token-bucket admission
+  upstream of the queue bound. ``QueueFullError`` carries
   ``retry_after_hint`` (queue depth over the observed drain rate) so
   shed callers back off proportionally instead of spinning.
 
@@ -260,6 +263,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.ft.checkpoint import (latest_step as ckpt_latest_step,
@@ -871,8 +875,6 @@ class EngineStats:
     # histograms serialize exactly through snapshot()/restore()
     ttft_ms: LatencyHistogram = dataclasses.field(
         default_factory=LatencyHistogram)      # submit -> first token
-    tok_ms: LatencyHistogram = dataclasses.field(
-        default_factory=LatencyHistogram)      # inter-token (decode) gap
     tenants: Dict[str, TenantStats] = dataclasses.field(
         default_factory=dict)
 
@@ -914,14 +916,13 @@ class EngineStats:
         d = {f.name: getattr(self, f.name)
              for f in dataclasses.fields(self)
              if f.name not in ("prefill_shapes", "decode_shapes",
-                               "ttft_ms", "tok_ms", "tenants")}
+                               "ttft_ms", "tenants")}
         d["prefill_shapes"] = sorted(self.prefill_shapes)
         d["decode_shapes"] = sorted(self.decode_shapes)
         d["tokens_per_decode_step"] = self.tokens_per_decode_step
         d["decode_rows_per_token"] = self.decode_rows_per_token
         d["prefix_hit_rate"] = self.prefix_hit_rate
         d["ttft"] = self.ttft_ms.as_dict()
-        d["tok"] = self.tok_ms.as_dict()
         d["tenants"] = {t: ts.as_dict()
                         for t, ts in sorted(self.tenants.items())}
         return d
@@ -1109,14 +1110,12 @@ class ServeEngine:
         self._fatal: Optional[str] = None
         self._step_count = 0
         # drain-rate estimate (terminals/sec EWMA) backing QueueFullError's
-        # retry_after_hint; per-rid submit/last-token times feed the TTFT
-        # and inter-token latency histograms
+        # retry_after_hint; per-rid submit times feed the TTFT histogram
         self._drain_rate = 0.0
         self._prev_step_t: Optional[float] = None
         self._prev_terminals = 0
         self._terminals = 0
         self._submit_t: Dict[int, float] = {}
-        self._last_tok_t: Dict[int, float] = {}
         self._store_fp: Optional[str] = None
         # streaming state: queued/running outputs, claimed-on-drain results,
         # lifecycle status/error, absolute deadlines, rid -> slot map
@@ -1406,7 +1405,6 @@ class ServeEngine:
         self._finished[rid] = self._out.pop(rid, [])
         self._deadline.pop(rid, None)
         self._submit_t.pop(rid, None)
-        self._last_tok_t.pop(rid, None)
         self._status[rid] = status
         self._error[rid] = error
         self._terminals += 1
@@ -1450,19 +1448,13 @@ class ServeEngine:
             self._finalize(rid, FINISHED)
             return
         # SLO instrumentation: first emitted token closes the TTFT window
-        # (submit -> first token); later tokens feed the inter-token gap
-        now = self._clock_fn()
+        # (submit -> first token)
         if not self._out[rid]:
             t0 = self._submit_t.get(rid)
             if t0 is not None:
-                ttft = (now - t0) * 1e3
+                ttft = (self._clock_fn() - t0) * 1e3
                 self.stats.ttft_ms.observe(ttft)
                 self.stats.tenant(r.tenant).ttft_ms.observe(ttft)
-        else:
-            tprev = self._last_tok_t.get(rid)
-            if tprev is not None:
-                self.stats.tok_ms.observe((now - tprev) * 1e3)
-        self._last_tok_t[rid] = now
         self._out[rid].append(tok)
         self.stats.tokens_generated += 1
         self.stats.tenant(r.tenant).tokens += 1
@@ -1550,105 +1542,121 @@ class ServeEngine:
         else:
             self.faults.on_launch(kind, index)
 
-    def _admit(self) -> None:
-        free = [i for i in range(self.batch) if not self._active[i]]
-        if not free:
-            return
-        # take from the queue, lazily skipping stale entries (requests
-        # cancelled / expired / shed while still queued stay in the heap
-        # until taken here — O(1) amortized instead of eager heap surgery)
-        rids: List[int] = []
-        while len(rids) < len(free) and len(self._sched):
-            for rid in self._sched.take(len(free) - len(rids)):
-                if rid in self._finished:
-                    continue
-                rids.append(rid)
-        if not rids:
-            return
-        # prefix matching against the RESIDENT index (donors placed in
-        # earlier rounds — active or finished-but-unreclaimed slots); a
-        # matched donor is pinned until the launch that copies it has run
-        match: Dict[int, Tuple[Optional[int], int]] = {}
-        for rid in rids:
+    def _chunk_inputs(self, chunk: List[int], Bb: int, Sb: int, match,
+                      self_place: Dict[int, int], avail: List[int]):
+        """Host inputs of one prefill launch: the chunk's slots (taken from
+        ``avail`` unless self-placed), left-padded tokens and positions,
+        prefix-cache donors and the optional keyword operands."""
+        slots = []
+        for rid in chunk:
+            s = self_place.get(rid)
+            if s is None:
+                s = avail.pop(0)
+            else:
+                # the consumer's own pin; released before eviction
+                # so _index_drop_slot sees an unreferenced slot
+                self._slot_refs[s] -= 1
+            slots.append(s)
+        toks = np.zeros((Bb, Sb), np.int32)
+        pos = np.zeros((Bb, Sb), np.int32)
+        donor_idx = np.asarray(slots, np.int32).copy()
+        mlen = np.zeros(Bb, np.int32)
+        prompts: List[np.ndarray] = []
+        for j, rid in enumerate(chunk):
             p = np.asarray(self._req[rid].prompt, np.int32).reshape(-1)
-            donor, m = self._match_prefix(p)
-            match[rid] = (donor, m)
-            if donor is not None:
-                self._slot_refs[donor] += 1
-        rids, avail, self_place = self._resolve_placement(rids, match, free)
+            prompts.append(p)
+            donor, m = match[rid]
+            T = p.shape[0] - m
+            toks[j, Sb - T:] = p[m:]
+            if m > 0:
+                # tail continues at positions m..m+T-1; pad writes
+                # park on ring slots m+T..m+Sb-1 with NEGATIVE
+                # stored positions (masked), clear of the copied
+                # donor rows [0, m)
+                pos[j, Sb - T:] = m + np.arange(T, dtype=np.int32)
+                pos[j, : Sb - T] = (
+                    m + T + np.arange(Sb - T, dtype=np.int32)
+                    - self.cache_len)
+                donor_idx[j] = donor
+                mlen[j] = m
+                self.stats.prefix_hits += 1
+                self.stats.prefill_tokens_saved += int(m)
+            else:
+                # pads get negative positions -> attention-masked
+                pos[j] = np.arange(Sb, dtype=np.int32) - (Sb - T)
+            self.stats.padded_prompt_tokens += Sb - T
+        for slot in slots:
+            self._index_drop_slot(slot)   # rows being overwritten
+        # the optional parts ride as kwargs so the positional
+        # layout (donated state at 3) is constant across runners;
+        # the kwarg set is fixed per engine configuration, so the
+        # jit cache still sees one calling convention
+        kw = {}
         if self.prefix_cache:
-            # lookups count ADMITTED requests only (deferred ones re-match
-            # next round; counting both would dilute the hit rate)
-            self.stats.prefix_lookups += len(rids)
-        by_bucket: Dict[int, List[int]] = {}
-        for rid in rids:
-            tail = self._req[rid].prompt_len - match[rid][1]
-            Sb = pick_bucket(tail, self.prompt_buckets)
-            by_bucket.setdefault(Sb, []).append(rid)
+            kw["donor_idx"] = jnp.asarray(donor_idx)
+            kw["match_len"] = jnp.asarray(mlen)
+        if self.runner.requires_extra:
+            kw["extra"] = jnp.asarray(np.stack([
+                np.asarray(self._req[rid].extra, np.float32)
+                for rid in chunk]))
+        return slots, toks, pos, prompts, kw
+
+    def _admit(self) -> None:
+        with TraceAnnotation("serve.admit", step=self._step_count):
+            free = [i for i in range(self.batch) if not self._active[i]]
+            if not free:
+                return
+            # take from the queue, lazily skipping stale entries (requests
+            # cancelled / expired / shed while still queued stay in the
+            # heap until taken here — O(1) amortized instead of eager heap
+            # surgery)
+            rids: List[int] = []
+            while len(rids) < len(free) and len(self._sched):
+                for rid in self._sched.take(len(free) - len(rids)):
+                    if rid in self._finished:
+                        continue
+                    rids.append(rid)
+            if not rids:
+                return
+            # prefix matching against the RESIDENT index (donors placed in
+            # earlier rounds — active or finished-but-unreclaimed slots); a
+            # matched donor is pinned until the launch that copies it has
+            # run
+            match: Dict[int, Tuple[Optional[int], int]] = {}
+            for rid in rids:
+                p = np.asarray(self._req[rid].prompt, np.int32).reshape(-1)
+                donor, m = self._match_prefix(p)
+                match[rid] = (donor, m)
+                if donor is not None:
+                    self._slot_refs[donor] += 1
+            rids, avail, self_place = self._resolve_placement(
+                rids, match, free)
+            if self.prefix_cache:
+                # lookups count ADMITTED requests only (deferred ones
+                # re-match next round; counting both would dilute the rate)
+                self.stats.prefix_lookups += len(rids)
+            by_bucket: Dict[int, List[int]] = {}
+            for rid in rids:
+                tail = self._req[rid].prompt_len - match[rid][1]
+                Sb = pick_bucket(tail, self.prompt_buckets)
+                by_bucket.setdefault(Sb, []).append(rid)
         for Sb in sorted(by_bucket):
             rids_b = by_bucket[Sb]
             for Bb in batch_split(len(rids_b), self.batch_buckets):
                 chunk, rids_b = rids_b[:Bb], rids_b[Bb:]
-                slots = []
-                for rid in chunk:
-                    s = self_place.get(rid)
-                    if s is None:
-                        s = avail.pop(0)
-                    else:
-                        # the consumer's own pin; released before eviction
-                        # so _index_drop_slot sees an unreferenced slot
-                        self._slot_refs[s] -= 1
-                    slots.append(s)
-                toks = np.zeros((Bb, Sb), np.int32)
-                pos = np.zeros((Bb, Sb), np.int32)
-                donor_idx = np.asarray(slots, np.int32).copy()
-                mlen = np.zeros(Bb, np.int32)
-                prompts: List[np.ndarray] = []
-                for j, rid in enumerate(chunk):
-                    p = np.asarray(self._req[rid].prompt,
-                                   np.int32).reshape(-1)
-                    prompts.append(p)
-                    donor, m = match[rid]
-                    T = p.shape[0] - m
-                    toks[j, Sb - T:] = p[m:]
-                    if m > 0:
-                        # tail continues at positions m..m+T-1; pad writes
-                        # park on ring slots m+T..m+Sb-1 with NEGATIVE
-                        # stored positions (masked), clear of the copied
-                        # donor rows [0, m)
-                        pos[j, Sb - T:] = m + np.arange(T, dtype=np.int32)
-                        pos[j, : Sb - T] = (
-                            m + T + np.arange(Sb - T, dtype=np.int32)
-                            - self.cache_len)
-                        donor_idx[j] = donor
-                        mlen[j] = m
-                        self.stats.prefix_hits += 1
-                        self.stats.prefill_tokens_saved += int(m)
-                    else:
-                        # pads get negative positions -> attention-masked
-                        pos[j] = np.arange(Sb, dtype=np.int32) - (Sb - T)
-                    self.stats.padded_prompt_tokens += Sb - T
-                for slot in slots:
-                    self._index_drop_slot(slot)   # rows being overwritten
-                # the optional parts ride as kwargs so the positional
-                # layout (donated state at 3) is constant across runners;
-                # the kwarg set is fixed per engine configuration, so the
-                # jit cache still sees one calling convention
-                kw = {}
-                if self.prefix_cache:
-                    kw["donor_idx"] = jnp.asarray(donor_idx)
-                    kw["match_len"] = jnp.asarray(mlen)
-                if self.runner.requires_extra:
-                    kw["extra"] = jnp.asarray(np.stack([
-                        np.asarray(self._req[rid].extra, np.float32)
-                        for rid in chunk]))
+                with TraceAnnotation("serve.admit", step=self._step_count):
+                    slots, toks, pos, prompts, kw = self._chunk_inputs(
+                        chunk, Bb, Sb, match, self_place, avail)
                 try:
                     self._on_launch("prefill", self.stats.prefill_calls,
                                     chunk)
-                    logits, ok, self.cache = self._prefill(
-                        self.params, jnp.asarray(toks), jnp.asarray(pos),
-                        self.cache,
-                        jnp.asarray(np.asarray(slots, np.int32)), **kw)
+                    with TraceAnnotation("serve.prefill.launch",
+                                         step=self._step_count,
+                                         rows=int(Bb), bucket=int(Sb)):
+                        logits, ok, self.cache = self._prefill(
+                            self.params, jnp.asarray(toks),
+                            jnp.asarray(pos), self.cache,
+                            jnp.asarray(np.asarray(slots, np.int32)), **kw)
                 # lint: allow-broad-except — fault-isolation boundary:
                 # classify_error decides request-fatal vs engine-fatal
                 except BaseException as e:
@@ -1673,36 +1681,40 @@ class ServeEngine:
                         self._slot_refs[donor] -= 1
                 self.stats.prefill_calls += 1
                 self.stats.prefill_shapes.add((Bb, Sb))
-                lg = np.asarray(logits)
-                okh = np.asarray(ok)
-                for j, (slot, rid) in enumerate(zip(slots, chunk)):
-                    if not okh[j]:
-                        # poisoned row: its NaN k/v already landed in the
-                        # slot — scrub back to blank rows (a masked NaN
-                        # still reaches attention via 0·NaN) and never
-                        # index/activate. Other rows are unaffected.
-                        self._scrub_slot(slot)
-                        self._finalize(rid, FAILED,
-                                       "non-finite logits in prefill "
-                                       "(request aborted; batch continues)")
-                        continue
-                    r = self._req[rid]
-                    self.stats.tenant(r.tenant).admitted += 1
-                    self._index_insert(slot, prompts[j])
-                    self._slot_req[slot] = rid
-                    self._rid_slot[rid] = slot
-                    self._slot_rng[slot] = r.sampling.make_rng()
-                    self._slot_pos[slot] = r.prompt_len
-                    self._slot_left[slot] = r.max_new
-                    self._active[slot] = True
-                    self._push_token(slot, lg[j])
+                with TraceAnnotation("serve.prefill.fetch",
+                                     step=self._step_count):
+                    lg = np.asarray(logits)
+                    okh = np.asarray(ok)
+                with TraceAnnotation("serve.prefill.sample",
+                                     step=self._step_count):
+                    for j, (slot, rid) in enumerate(zip(slots, chunk)):
+                        if not okh[j]:
+                            # poisoned row: its NaN k/v already landed in
+                            # the slot — scrub back to blank rows (a masked
+                            # NaN still reaches attention via 0·NaN) and
+                            # never index/activate. Other rows are
+                            # unaffected.
+                            self._scrub_slot(slot)
+                            self._finalize(
+                                rid, FAILED, "non-finite logits in prefill "
+                                "(request aborted; batch continues)")
+                            continue
+                        r = self._req[rid]
+                        self.stats.tenant(r.tenant).admitted += 1
+                        self._index_insert(slot, prompts[j])
+                        self._slot_req[slot] = rid
+                        self._rid_slot[rid] = slot
+                        self._slot_rng[slot] = r.sampling.make_rng()
+                        self._slot_pos[slot] = r.prompt_len
+                        self._slot_left[slot] = r.max_new
+                        self._active[slot] = True
+                        self._push_token(slot, lg[j])
 
     # -- decode -------------------------------------------------------------
-    def _decode_step(self) -> None:
-        act = np.nonzero(self._active)[0]
+    def _decode_rows(self, act: np.ndarray) -> Tuple[int, np.ndarray]:
+        """The decode bucket for the active slots ``act`` and the slot rows
+        it launches: ``act`` first, then pad lanes."""
         n = act.size
-        if n == 0:
-            return
         Bb = pick_bucket(n, self.decode_buckets)
         # pad lanes borrow *distinct free* slot rows (there are always
         # enough: Bb <= batch so Bb - n <= batch - n). The scatter-back
@@ -1729,6 +1741,15 @@ class ServeEngine:
             else:
                 idx = np.concatenate([act, free[: Bb - n]])
         idx = idx.astype(np.int32)
+        return Bb, idx
+
+    def _decode_step(self) -> None:
+        with TraceAnnotation("serve.decode.prep", step=self._step_count):
+            act = np.nonzero(self._active)[0]
+            n = act.size
+            if n == 0:
+                return
+            Bb, idx = self._decode_rows(act)
         # wrapped launch with ONE retry for transient (pre-launch) faults:
         # the injector's fired-set guarantees a scheduled fault does not
         # refire, so the retry runs the same launch with intact buffers. A
@@ -1739,11 +1760,15 @@ class ServeEngine:
             try:
                 self._on_launch("decode", self.stats.decode_steps,
                                 [self._slot_req[int(s)] for s in act])
-                logits, ok, self.cache = self._decode(
-                    self.params, jnp.asarray(self._slot_last[idx][:, None]),
-                    self.cache, jnp.asarray(self._slot_pos[idx]),
-                    jnp.asarray(idx),
-                )
+                with TraceAnnotation("serve.decode.launch",
+                                     step=self._step_count,
+                                     rows=int(Bb)):
+                    logits, ok, self.cache = self._decode(
+                        self.params,
+                        jnp.asarray(self._slot_last[idx][:, None]),
+                        self.cache, jnp.asarray(self._slot_pos[idx]),
+                        jnp.asarray(idx),
+                    )
                 break
             # lint: allow-broad-except — fault-isolation boundary:
             # classify_error decides retry vs engine-fatal
@@ -1757,20 +1782,23 @@ class ServeEngine:
         self.stats.decode_rows += int(Bb)
         self.stats.decode_shapes.add(int(Bb))
         self._slot_pos[act] += 1
-        lg = np.asarray(logits)
-        okh = np.asarray(ok)
-        for j, slot in enumerate(act):
-            slot = int(slot)
-            if not okh[j]:
-                # poisoned row: abort just this request; scrub its rows
-                # (NaN k/v reach attention even masked) and drop it from
-                # the prefix index. All other rows continue unaffected.
-                self._finalize(self._slot_req[slot], FAILED,
-                               "non-finite logits in decode "
-                               "(request aborted; batch continues)",
-                               scrub=True)
-                continue
-            self._push_token(slot, lg[j])
+        with TraceAnnotation("serve.decode.fetch", step=self._step_count):
+            lg = np.asarray(logits)
+            okh = np.asarray(ok)
+        with TraceAnnotation("serve.decode.sample", step=self._step_count):
+            for j, slot in enumerate(act):
+                slot = int(slot)
+                if not okh[j]:
+                    # poisoned row: abort just this request; scrub its
+                    # rows (NaN k/v reach attention even masked) and drop
+                    # it from the prefix index. All other rows continue
+                    # unaffected.
+                    self._finalize(self._slot_req[slot], FAILED,
+                                   "non-finite logits in decode "
+                                   "(request aborted; batch continues)",
+                                   scrub=True)
+                    continue
+                self._push_token(slot, lg[j])
 
     def audit(self, raise_on_violation: bool = False):
         """Run every single-engine structural contract (see the module
@@ -1909,27 +1937,29 @@ class ServeEngine:
         ``snapshot_every`` steps. Returns True while work remains (active
         slots or queued requests). Raises :class:`EngineFatalError` (and
         marks the engine dead) on unrecoverable launch errors."""
-        self._check_alive()
-        t0 = self._clock_fn()
-        if self.faults is not None:
-            self.faults.on_step(self._step_count)
-        self._expire_overdue()
-        self._admit()
-        self._decode_step()
-        self._step_count += 1
-        now = self._clock_fn()
-        self._observe_drain(now)
-        if self._watchdog.observe(self._step_count, now - t0) != "ok":
-            self.stats.slow_steps += 1
-        # auto-snapshot skips an EMPTY engine (no queued, running, or
-        # unclaimed requests): such a snapshot resumes nothing — restoring
-        # it is refused — and idle-loop callers would otherwise overwrite
-        # the last useful snapshot with a useless one
-        if (self.snapshot_dir is not None and self.snapshot_every > 0
-                and self._step_count % self.snapshot_every == 0
-                and (self._req or self._finished)):
-            self.snapshot()
-        return bool(self._active.any() or len(self._sched))
+        with TraceAnnotation("serve.step", step=self._step_count):
+            self._check_alive()
+            t0 = self._clock_fn()
+            if self.faults is not None:
+                self.faults.on_step(self._step_count)
+            self._expire_overdue()
+            self._admit()
+            self._decode_step()
+            self._step_count += 1
+            now = self._clock_fn()
+            self._observe_drain(now)
+            if self._watchdog.observe(self._step_count, now - t0) != "ok":
+                self.stats.slow_steps += 1
+            # auto-snapshot skips an EMPTY engine (no queued, running, or
+            # unclaimed requests): such a snapshot resumes nothing —
+            # restoring it is refused — and idle-loop callers would
+            # otherwise overwrite the last useful snapshot with a useless
+            # one
+            if (self.snapshot_dir is not None and self.snapshot_every > 0
+                    and self._step_count % self.snapshot_every == 0
+                    and (self._req or self._finished)):
+                self.snapshot()
+            return bool(self._active.any() or len(self._sched))
 
     def poll(self, req_id: int) -> RequestState:
         """Snapshot a submitted request's progress without consuming it:
@@ -2088,13 +2118,11 @@ class ServeEngine:
                          for rid, t in self._finished.items()],
             "deadline_remaining_s": [[rid, max(0.0, t - now)]
                                      for rid, t in self._deadline.items()],
-            # submit/last-token times as AGES (like deadlines): absolute
-            # clocks don't survive process boundaries, relative ones do
+            # submit times as AGES (like deadlines): absolute clocks don't
+            # survive process boundaries, relative ones do
             "timing": {
                 "submit_age_s": [[rid, now - t]
                                  for rid, t in self._submit_t.items()],
-                "last_tok_age_s": [[rid, now - t]
-                                   for rid, t in self._last_tok_t.items()],
             },
             "sched": self._sched.state_dict(),
             "rid_slot": [[rid, int(s)] for rid, s in self._rid_slot.items()],
@@ -2124,8 +2152,7 @@ class ServeEngine:
             # fixed-bucket histograms serialize exactly: bucket counts in,
             # bucket counts out — restore resumes the same p50/p99
             "stats_hists": {
-                "ttft": list(self.stats.ttft_ms.counts),
-                "tok": list(self.stats.tok_ms.counts)},
+                "ttft": list(self.stats.ttft_ms.counts)},
             "stats_tenants": [
                 [t, {"submitted": ts.submitted, "admitted": ts.admitted,
                      "completed": ts.completed, "rejected": ts.rejected,
@@ -2231,8 +2258,6 @@ class ServeEngine:
         tm = meta["timing"]
         self._submit_t = {int(rid): now - float(age)
                           for rid, age in tm["submit_age_s"]}
-        self._last_tok_t = {int(rid): now - float(age)
-                            for rid, age in tm["last_tok_age_s"]}
         self._sched = Scheduler(self.policy, max_queue=self.max_queue,
                                 shed_policy=self.shed_policy,
                                 tenant_weights=self.tenant_weights,
@@ -2267,9 +2292,9 @@ class ServeEngine:
             (int(b), int(s)) for b, s in meta["stats_shapes"]["prefill"]}
         self.stats.decode_shapes = {
             int(b) for b in meta["stats_shapes"]["decode"]}
-        hists = meta["stats_hists"]
-        self.stats.ttft_ms = LatencyHistogram(hists["ttft"])
-        self.stats.tok_ms = LatencyHistogram(hists["tok"])
+        # older snapshots also carry an inter-token histogram ("tok") and
+        # last-token ages; nothing reads them any more
+        self.stats.ttft_ms = LatencyHistogram(meta["stats_hists"]["ttft"])
         self.stats.tenants = {}
         for t, d in meta["stats_tenants"]:
             ts = self.stats.tenant(t)

@@ -232,6 +232,7 @@ class DecoderRunner(ModelRunner):
         ok = jnp.isfinite(logits).all(axis=-1)
         return logits, ok, self.place_state(state, new_sub, slot_idx)
 
+    @jax.named_scope("kv_move")
     def gather_state(self, src, idx):
         """Gather slot rows into a sub-batch state (inverse of
         ``place_state``); slot axis 0 plain, 1 repeat-stacked."""
@@ -242,6 +243,7 @@ class DecoderRunner(ModelRunner):
             out.append(jax.tree.map(take, s_g))
         return out
 
+    @jax.named_scope("kv_move")
     def place_state(self, dst, src, idx):
         """Scatter per-request state rows into slot rows. The slot axis is
         0 for plain groups and 1 for repeat-stacked groups (leading scan
@@ -327,9 +329,11 @@ class EncDecRunner(ModelRunner):
         ok = jnp.isfinite(logits).all(axis=-1)
         return logits, ok, self.place_state(state, new_sub, slot_idx)
 
+    @jax.named_scope("kv_move")
     def gather_state(self, state, idx):
         return jax.tree.map(lambda s: s[:, idx], state)
 
+    @jax.named_scope("kv_move")
     def place_state(self, dst, src, idx):
         return jax.tree.map(
             lambda d, s: d.at[:, idx].set(s.astype(d.dtype)), dst, src)

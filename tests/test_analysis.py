@@ -19,8 +19,9 @@ import pytest
 from repro.analysis import (Contract, DenseFallbackDot, DonatedInputsAliased,
                             LaunchBudget, NoDenseDotGeneral, NoFFT,
                             NoWeightConcat, NoWeightFFT, QuantizedTableDtypes,
-                            StructuralContractError, collect_pure_vars,
-                            iter_eqns, run_contract, source_location)
+                            ScopedContractions, StructuralContractError,
+                            collect_pure_vars, iter_eqns, iter_scoped_eqns,
+                            run_contract, source_location)
 from repro.analysis.lint import ALLOW_BROAD_EXCEPT_MARKER, lint_file
 from repro.kernels.block_circulant import build_plan
 from repro.kernels.block_circulant.ops import (count_pallas_launches,
@@ -187,6 +188,72 @@ def test_dense_fallback_rule_fires_only_on_weight_side():
     vs2 = DenseFallbackDot([(24, 40)], n_param_invars=1).check(jp2)
     assert all("(24, 40)" not in str(v) or v.primitive != "dot_general"
                for v in vs2) or vs2 == []
+
+
+def test_scoped_walker_carries_enclosing_scopes_into_sub_jaxprs():
+    """A sub-jaxpr's own name stacks start empty: the scopes of the scan
+    (and checkpoint) that hold it must be carried down."""
+    def body(c, w):
+        return c @ w, None
+
+    def f(x, ws):
+        with jax.named_scope("outer"):
+            y = jax.checkpoint(lambda x: jax.lax.scan(body, x, ws)[0])(x)
+        with jax.named_scope("head"):
+            return y @ ws[0]
+
+    jp = jax.make_jaxpr(f)(_rand((4, 4)), _rand((3, 4, 4), seed=1))
+    dots = [sc for e, sc in iter_scoped_eqns(jp)
+            if e.primitive.name == "dot_general"]
+    assert sorted(dots) == [("head",), ("outer",)]
+
+
+def test_scoped_contractions_rule_fires_outside_every_scope():
+    w = _rand((8, 8))
+
+    def step(x):
+        with jax.named_scope("attention"):
+            with jax.named_scope("circulant"):
+                x = x @ w
+            x = x @ w
+        with jax.named_scope("kv_move"):
+            x = x[::-1]
+        with jax.named_scope("head"):
+            x = jnp.einsum("bd,vd->bv", x, w)
+        return x @ w                              # the scope a refactor lost
+
+    jp = jax.make_jaxpr(step)(_rand((2, 8), seed=1))
+    vs = ScopedContractions().check(jp)
+    assert len(vs) == 1 and vs[0].primitive == "dot_general"
+    assert vs[0].where and "test_analysis.py:" in vs[0].where
+    plan = build_plan(_rand((3, 3, 8)))
+    x = _rand((4, 24), seed=2)
+    assert len(ScopedContractions().check(
+        jax.make_jaxpr(plan.apply)(x))) == 1     # an unscoped pallas_call
+    jp2 = jax.make_jaxpr(jax.named_scope("circulant")(plan.apply))(x)
+    assert ScopedContractions().check(jp2) == []
+
+
+def test_decode_surfaces_carry_the_scope_rule():
+    """The dense decoders' decode surfaces are held to the scopes (the
+    engine audit passes, so every contraction of theirs is scoped); MoE and
+    recurrent families have contractions no scope names, and are not."""
+    from repro.analysis.contracts import (_decode_rules, _smoke_engine,
+                                          audit_engine)
+    from repro.configs.registry import get_smoke
+    from repro.launch.specs import build_model
+    from repro.nn.module import init_params
+
+    def engine(arch):
+        cfg = get_smoke(arch)
+        model = build_model(cfg)
+        return _smoke_engine(model, cfg, init_params(model.specs(), 0), "off")
+
+    eng = engine("qwen3-0.6b")
+    assert [type(r) for r in _decode_rules(eng)] == [ScopedContractions]
+    assert audit_engine(eng) == []
+    for arch in ("qwen3-moe-235b-a22b", "rwkv6-7b"):
+        assert _decode_rules(engine(arch)) == ()
 
 
 def test_launch_budget_points_at_excess_launch():

@@ -18,6 +18,7 @@ untrained model may generate any token id).
 """
 
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -383,6 +384,37 @@ def test_fatal_fault_recovers_via_snapshot(lm, base6, tmp_path):
             "fault-free run")
     assert twin.stats.recoveries == 1
     _no_leaks(twin)
+
+
+def test_restore_reads_past_an_older_snapshots_token_gaps(lm, tmp_path):
+    """Snapshots from before the engine stopped timing inter-token gaps
+    carry a ``"tok"`` histogram and last-token ages; restore ignores
+    them and resumes as from a current snapshot."""
+    from repro.ft.checkpoint import restore_checkpoint, save_checkpoint
+
+    eng = _engine(lm, snapshot_dir=str(tmp_path))
+    rids = [eng.submit(r) for r in _mix(0, 3)]
+    for _ in range(2):
+        eng.step()
+    step = eng._step_count
+    eng.snapshot()
+    ttft = eng.stats.ttft_ms.count
+    state = restore_checkpoint(str(tmp_path), step)
+    meta = json.loads(bytes(np.asarray(state["meta"])).decode("utf-8"))
+    meta["stats_hists"]["tok"] = [1] * len(meta["stats_hists"]["ttft"])
+    meta["timing"]["last_tok_age_s"] = [[rid, 0.01] for rid in rids]
+    state["meta"] = np.frombuffer(json.dumps(meta).encode("utf-8"),
+                                  np.uint8)
+    save_checkpoint(str(tmp_path), step + 1, state)
+    _drive(eng)
+
+    twin = _engine(lm, snapshot_dir=str(tmp_path))
+    assert twin.restore() == step + 1
+    assert twin.stats.ttft_ms.count == ttft
+    assert "tok" not in twin.stats.as_dict()
+    _drive(twin)
+    for rid in rids:
+        assert twin.poll(rid).tokens == eng.poll(rid).tokens
 
 
 def test_restore_needs_fresh_engine(lm, tmp_path):

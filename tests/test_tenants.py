@@ -288,7 +288,6 @@ class TestEngineTenancy:
         assert s.tenants["b"].completed == 2
         assert s.tenants["a"].tokens == 16 and s.tenants["b"].tokens == 8
         assert s.ttft_ms.count == 6
-        assert s.tok_ms.count == 6 * 4 - 6   # every non-first token
         for rid in rids:
             assert eng.poll(rid).status == "FINISHED"
 
