@@ -14,6 +14,9 @@ with the distinct layers unrolled inside the body.
 
 Caches mirror the group structure: a list (one entry per group) of dicts
 keyed ``l{i}`` with a leading repeat axis, scanned as xs/ys alongside params.
+Decode in place (``decode_step(..., slot_idx=...)``) takes the serving
+engine's whole slot pool instead: it rides the layer scan as the carry,
+indexed by the scan counter, and each step writes only its new entries.
 """
 
 from __future__ import annotations
@@ -26,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import LayerGroup, LayerSpec, ModelConfig
-from repro.nn.attention import Attention, init_kv_cache
+from repro.nn.attention import (Attention, init_kv_cache, pool_index,
+                                pool_rows)
 from repro.nn.ffn import SwiGLU
 from repro.nn.layers import Embedding, RMSNorm
 from repro.nn.moe import MoE
@@ -46,6 +50,20 @@ def local_attn_cache_len(cfg: ModelConfig, cache_len: int) -> int:
     must refuse those configs)."""
     w = cfg.sliding_window or cache_len
     return min(w, cache_len)
+
+
+@jax.named_scope("kv_move")
+def _take_rows(pool, slot_idx, layer):
+    """Pool rows ``slot_idx`` of one layer's state as a sub-batch."""
+    return jax.tree.map(lambda a: pool_rows(a, layer, slot_idx), pool)
+
+
+@jax.named_scope("kv_move")
+def _put_rows(pool, rows, slot_idx, layer):
+    """Scatter a sub-batch of one layer's state back to pool rows."""
+    return jax.tree.map(
+        lambda a, r: a.at[pool_index(layer, slot_idx)].set(r.astype(a.dtype)),
+        pool, rows)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,19 +183,31 @@ class HybridDecoderLM:
     # one layer
     # ------------------------------------------------------------------
     def _apply_layer(self, lspec: LayerSpec, stack, p, x, positions, cache,
-                     mask=None, moe_no_drop=False):
+                     mask=None, moe_no_drop=False, slot_idx=None, layer=None):
+        """One layer. With ``slot_idx``, ``cache`` is this layer's slice of
+        the slot pool (behind the repeat axis at ``layer``): attention
+        writes and reads it in place; recurrent state, small and rewritten
+        whole every step, is taken at ``slot_idx``, stepped and put back."""
         cfg = self.cfg
+        attn = lspec.mixer in ("attn", "attn_local")
+        if slot_idx is not None and not attn:
+            rows = _take_rows(cache, slot_idx, layer)
+            x, rows, aux = self._apply_layer(lspec, stack, p, x, positions,
+                                             rows, mask=mask,
+                                             moe_no_drop=moe_no_drop)
+            return x, _put_rows(cache, rows, slot_idx, layer), aux
         ln1 = RMSNorm(cfg.d_model, stack=stack)
         ln2 = RMSNorm(cfg.d_model, stack=stack)
         aux = jnp.zeros((), jnp.float32)
 
         h = ln1(p["ln1"], x)
         mixer = self._mixer(lspec, stack)
-        if lspec.mixer in ("attn", "attn_local"):
+        if attn:
             # attention masks pads through negative positions already; the
             # validity mask is only threaded to the recurrent mixers so
             # attention-family jaxprs are unchanged
-            mo, new_cache = mixer(p["mixer"], h, positions, cache=cache)
+            mo, new_cache = mixer(p["mixer"], h, positions, cache=cache,
+                                  slot_idx=slot_idx, layer=layer)
         elif mask is not None:
             mo, new_cache = mixer(p["mixer"], h, cache=cache, mask=mask)
         else:
@@ -212,10 +242,15 @@ class HybridDecoderLM:
     # group execution (scan over repeats)
     # ------------------------------------------------------------------
     def _apply_group(self, gi, group: LayerGroup, params_g, x, positions,
-                     cache_g, mask=None, moe_no_drop=False):
+                     cache_g, mask=None, moe_no_drop=False, slot_idx=None):
+        """The group's layers over ``x``. A cache rides the layer scan as
+        xs/ys; with ``slot_idx`` the cache is the slot pool, which rides as
+        the carry instead (indexed by the scan counter, so each layer
+        writes its new entries in place and nothing else is copied)."""
         cfg = self.cfg
         stack = (group.repeat,) if group.repeat > 1 else ()
         use_cache = cache_g is not None
+        in_place = slot_idx is not None
 
         # Remat at LAYER granularity: a multi-layer group body (gemma3's
         # 6-layer 5:1 pattern, jamba's 8-layer period) must not require all
@@ -223,42 +258,46 @@ class HybridDecoderLM:
         # measured 310 GB/dev on gemma3 train_4k with body-level remat only.
         # ``mask`` rides as a traced arg (None is an empty pytree);
         # ``moe_no_drop`` is a static Python bool closed over, never traced.
-        def one_layer(lspec, p_li, x, positions, mask, c):
+        def one_layer(lspec, p_li, x, positions, mask, c, slot_idx, layer):
             return self._apply_layer(lspec, (), p_li, x, positions, c,
-                                     mask=mask, moe_no_drop=moe_no_drop)
+                                     mask=mask, moe_no_drop=moe_no_drop,
+                                     slot_idx=slot_idx, layer=layer)
 
         layer_fn = (jax.checkpoint(one_layer, static_argnums=(0,))
                     if cfg.remat != "none" else one_layer)
 
         def body(carry, xs):
-            x, aux = carry
-            p_slice, c_slice = xs
+            x, aux, pool = carry
+            p_slice, c_slice, layer = xs
             new_c = {}
             for li, lspec in enumerate(group.layers):
-                c = c_slice[f"l{li}"] if use_cache else None
+                key = f"l{li}"
+                c = (pool if in_place else c_slice)[key] if use_cache else None
                 x, nc, a = layer_fn(
-                    lspec, p_slice[f"l{li}"], x, positions, mask, c
-                )
+                    lspec, p_slice[key], x, positions, mask, c, slot_idx,
+                    layer)
                 if use_cache:
-                    new_c[f"l{li}"] = nc
+                    new_c[key] = nc
                 aux = aux + a
-            return (x, aux), (new_c if use_cache else None)
+            if in_place:
+                return (x, aux, new_c), None
+            return (x, aux, None), (new_c if use_cache else None)
 
         # layer_fn already remats each layer; the scan saves only the
         # inter-layer residual stream per step (checkpointing the body as
         # well would triple forward work for no memory win).
         aux0 = jnp.zeros((), jnp.float32)
+        carry = (x, aux0, cache_g if in_place else None)
+        xs_cache = cache_g if use_cache and not in_place else None
         if group.repeat == 1:
-            (x, aux), new_cache = body(
-                (x, aux0), (params_g, cache_g if use_cache else None)
-            )
-            return x, new_cache, aux
-
-        (x, aux), new_cache = jax.lax.scan(
-            body, (x, aux0),
-            (params_g, cache_g if use_cache else None),
-        )
-        return x, new_cache, aux
+            (x, aux, pool), new_cache = body(carry,
+                                             (params_g, xs_cache, None))
+        else:
+            layers = (jnp.arange(group.repeat, dtype=jnp.int32)
+                      if in_place else None)
+            (x, aux, pool), new_cache = jax.lax.scan(
+                body, carry, (params_g, xs_cache, layers))
+        return x, (pool if in_place else new_cache), aux
 
     # ------------------------------------------------------------------
     # public entry points
@@ -273,6 +312,7 @@ class HybridDecoderLM:
         cache: Optional[List[dict]] = None,
         logits_mode: str = "all",                 # all | last | none
         moe_no_drop: bool = False,
+        slot_idx: Optional[jax.Array] = None,
     ):
         """Training / prefill forward. Returns (logits, new_cache, aux).
 
@@ -287,7 +327,9 @@ class HybridDecoderLM:
         and the mask makes them contribute exactly nothing to recurrent
         state (attention already masks pads via negative positions, so
         attention-family traces are unchanged). ``moe_no_drop=True`` is the
-        serving MoE dispatch (see :class:`repro.nn.moe.MoE`).
+        serving MoE dispatch (see :class:`repro.nn.moe.MoE`). ``slot_idx``
+        makes ``cache`` the whole slot pool, updated in place (see
+        :meth:`decode_step`).
         """
         cfg = self.cfg
         emb = Embedding(cfg.vocab, cfg.d_model, dtype=cfg.param_dtype)
@@ -309,7 +351,7 @@ class HybridDecoderLM:
             cg = cache[gi] if cache is not None else None
             x, nc, a = self._apply_group(
                 gi, group, params[f"group{gi}"], x, positions, cg,
-                mask=mask, moe_no_drop=moe_no_drop,
+                mask=mask, moe_no_drop=moe_no_drop, slot_idx=slot_idx,
             )
             new_caches.append(nc)
             aux = aux + a
@@ -359,12 +401,22 @@ class HybridDecoderLM:
         cache: List[dict],
         pos: jax.Array,          # (B,) current absolute position
         moe_no_drop: bool = False,
+        slot_idx: Optional[jax.Array] = None,   # (B,) pool rows
     ):
-        """One-token decode against the cache. Returns (logits, cache)."""
+        """One-token decode against the cache. Returns (logits, cache).
+
+        Without ``slot_idx`` the cache holds exactly the ``B`` rows being
+        decoded. With it, ``cache`` is the whole slot pool (any number of
+        rows) and batch row ``b`` decodes pool row ``slot_idx[b]`` in
+        place: per layer, attention writes the ``B`` new entries and reads
+        the rows straight from the pool, recurrent state is taken and put
+        back per row. ``slot_idx`` must hold distinct rows. The returned
+        pool equals gathering the rows, decoding them without
+        ``slot_idx`` and scattering them back, bit for bit."""
         positions = pos[:, None].astype(jnp.int32)
         logits, new_cache, _ = self.forward(
             params, tokens, positions=positions, cache=cache,
-            moe_no_drop=moe_no_drop,
+            moe_no_drop=moe_no_drop, slot_idx=slot_idx,
         )
         return logits[:, -1], new_cache
 

@@ -30,7 +30,8 @@ from repro.configs.base import ModelConfig
 from repro.nn.layers import RMSNorm, apply_rope, rotary
 from repro.nn.linear import Linear
 
-__all__ = ["Attention", "init_kv_cache", "flash_attention"]
+__all__ = ["Attention", "init_kv_cache", "flash_attention", "pool_index",
+           "pool_rows"]
 
 _NEG = -2.0e38
 
@@ -42,6 +43,21 @@ def init_kv_cache(batch, cache_len, n_kv, head_dim, dtype):
         "v": jnp.zeros((batch, cache_len, n_kv, head_dim), dtype),
         "pos": -jnp.ones((batch, cache_len), jnp.int32),
     }
+
+
+def pool_index(layer, *idx):
+    """Index into a slot-pool leaf: ``idx`` from the slot axis on, behind
+    the leading repeat axis at ``layer`` when the pool is repeat-stacked
+    (``layer`` is None for a plain group)."""
+    return idx if layer is None else (layer,) + idx
+
+
+def pool_rows(leaf, layer, slot_idx):
+    """Rows ``slot_idx`` of a slot-pool leaf (of layer ``layer`` when the
+    pool is repeat-stacked). The layer is sliced first and the rows
+    gathered from that: on a TPU the compiler then streams the rows into
+    their consumer, where one gather over both axes materializes a copy."""
+    return (leaf if layer is None else leaf[layer])[slot_idx]
 
 
 # ---------------------------------------------------------------------------
@@ -308,7 +324,14 @@ class Attention:
         kv_x: Optional[jax.Array] = None,   # cross-attn source
         kv_positions: Optional[jax.Array] = None,
         update_cache: bool = True,
+        slot_idx: Optional[jax.Array] = None,
+        layer: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Optional[dict]]:
+        """With ``slot_idx`` the cache is the whole slot pool (decode in
+        place): batch row ``b`` is pool row ``slot_idx[b]`` (behind the
+        repeat axis at ``layer``), only the new entries are written, and
+        attention reads the rows straight from the pool. Returns the
+        updated pool."""
         cfg = self.cfg
         B, S, _ = x.shape
         hd, HQ, HKV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
@@ -356,6 +379,14 @@ class Attention:
                 k_att = new_cache["k"].astype(x.dtype)
                 v_att = new_cache["v"].astype(x.dtype)
                 kv_pos = new_cache["pos"]
+            elif slot_idx is not None:
+                new_cache = self._write_cache(cache, k, v, positions,
+                                              slot_idx, layer)
+                k_att = pool_rows(new_cache["k"], layer, slot_idx).astype(
+                    x.dtype)
+                v_att = pool_rows(new_cache["v"], layer, slot_idx).astype(
+                    x.dtype)
+                kv_pos = pool_rows(new_cache["pos"], layer, slot_idx)
             else:
                 new_cache = self._write_cache(cache, k, v, positions)
                 if S == 1 or S < cache["k"].shape[1]:
@@ -389,19 +420,25 @@ class Attention:
         return out, new_cache
 
     # -- cache write -------------------------------------------------------
-    def _write_cache(self, cache, k, v, positions):
-        """Ring-buffer write at slot = pos % cache_len. If the incoming span
-        exceeds the cache, only the trailing cache_len tokens are written
-        (their slots are unique, so the scatter is well-defined)."""
+    @jax.named_scope("kv_move")
+    def _write_cache(self, cache, k, v, positions, slot_idx=None, layer=None):
+        """Ring-buffer write at slot = pos % ring_len, the leaf's own length
+        (``attn_local`` rings are shorter than ``cache_len``). Batch row
+        ``b`` writes cache row ``b``, or pool row ``slot_idx[b]`` (behind
+        the repeat axis at ``layer``) when decoding in place on the slot
+        pool. If the incoming span exceeds the ring, only the trailing
+        ring_len tokens are written (their slots are unique, so the scatter
+        is well-defined)."""
         B, S = positions.shape
-        cache_len = cache["k"].shape[1]
+        cache_len = cache["k"].shape[-3]
         if S >= cache_len:
             k, v = k[:, -cache_len:], v[:, -cache_len:]
             positions = positions[:, -cache_len:]
         slots = (positions % cache_len).astype(jnp.int32)
-        bidx = jnp.arange(B, dtype=jnp.int32)[:, None]
+        rows = jnp.arange(B, dtype=jnp.int32) if slot_idx is None else slot_idx
+        at = pool_index(layer, rows[:, None], slots)
         return {
-            "k": cache["k"].at[bidx, slots].set(k.astype(cache["k"].dtype)),
-            "v": cache["v"].at[bidx, slots].set(v.astype(cache["v"].dtype)),
-            "pos": cache["pos"].at[bidx, slots].set(positions.astype(jnp.int32)),
+            "k": cache["k"].at[at].set(k.astype(cache["k"].dtype)),
+            "v": cache["v"].at[at].set(v.astype(cache["v"].dtype)),
+            "pos": cache["pos"].at[at].set(positions.astype(jnp.int32)),
         }

@@ -37,9 +37,10 @@ of that split, applied at three levels:
 
 * **Decode-side slot compaction** — the paper's throughput argument (and
   CirCNN's, arXiv:1708.08917) is that no FFT → ∘ → IFFT lane ever carries
-  dead data. Before each decode launch the engine gathers the *active*
-  slots' cache rows, last tokens, and positions into a bucket-shaped
-  sub-batch, decodes there, and scatters logits and cache rows back. In the
+  dead data. Before each decode launch the engine collects the *active*
+  slots' rows, last tokens, and positions into a bucket-shaped launch; the
+  runner decodes those rows in place on the slot pool (only each row's new
+  cache entries are written) and returns one logits row per lane. In the
   tail of a batch one live request pays for ``pick_bucket(1)`` rows of
   work, not ``batch`` rows (``EngineStats.decode_rows`` /
   ``decode_rows_per_token`` make the saving measurable). Compaction is a
@@ -99,10 +100,10 @@ of that split, applied at three levels:
 
 * **Donated decode buffers** (``donate=True``, default) — every
   prefill/decode executable takes the slot cache through
-  ``jax.jit(..., donate_argnums)``, so the compaction scatter updates the
-  cache in place (XLA input-output aliasing) instead of allocating and
-  copying a second full cache per step — the PR-3 gather→decode→scatter
-  path's extra HBM round-trip disappears. The engine threads the returned
+  ``jax.jit(..., donate_argnums)``, so prefill's place-back scatter and
+  decode's new entries update the cache in place (XLA input-output
+  aliasing) instead of allocating and copying a second full cache per
+  step. The engine threads the returned
   cache handle through every call (a donated input buffer is invalid
   after the call), and ``prewarm()`` COMMITS its warm-up results for the
   same reason: discarding them would kill the live cache. Donation never
@@ -943,8 +944,8 @@ class ServeEngine:
       shapes so the engine compiles at most ``max_prefill_variants``
       prefill executables;
     * decode launches compact the active slots into the smallest
-      ``decode_buckets`` batch that holds them (gather rows → decode →
-      scatter rows back), so the engine compiles at most
+      ``decode_buckets`` batch that holds them (the runner decodes those
+      rows in place on the slot pool), so the engine compiles at most
       ``len(decode_buckets)`` decode executables and the tail of a batch
       never pays full-slot row work;
     * frozen frequency weights are computed exactly once at construction
@@ -957,8 +958,9 @@ class ServeEngine:
       slot and prefills only the tail (see the module docstring for the
       match → copy → tail-prefill → refcount → evict lifecycle);
     * ``donate=True`` (default) donates the cache into every executable so
-      the place-back scatter updates HBM in place — no per-step full-cache
-      copy; all callers thread the returned handle.
+      prefill's place-back scatter and decode's new entries update HBM in
+      place — no per-step full-cache copy; all callers thread the returned
+      handle.
 
     Streaming API: ``submit(request) -> req_id`` enqueues, ``step()``
     advances admission plus one decode round, ``poll(req_id)`` snapshots
@@ -1086,9 +1088,10 @@ class ServeEngine:
         self._prefill_fn = self.runner.prefill
         self._decode_fn = self.runner.decode
         # donating the cache argument lets XLA alias input and output slot
-        # caches: the place-back scatter updates HBM in place instead of
-        # writing a second full cache per launch. Every caller threads the
-        # returned handle (the donated input is dead after the call).
+        # caches: prefill's place-back scatter and decode's new entries
+        # update HBM in place instead of writing a second full cache per
+        # launch. Every caller threads the returned handle (the donated
+        # input is dead after the call).
         if self.donate:
             self._prefill = jax.jit(self._prefill_fn, donate_argnums=(3,))
             self._decode = jax.jit(self._decode_fn, donate_argnums=(2,))
@@ -1717,8 +1720,8 @@ class ServeEngine:
         n = act.size
         Bb = pick_bucket(n, self.decode_buckets)
         # pad lanes borrow *distinct free* slot rows (there are always
-        # enough: Bb <= batch so Bb - n <= batch - n). The scatter-back
-        # therefore has no duplicate indices, and pad-lane writes land on
+        # enough: Bb <= batch so Bb - n <= batch - n). The in-place decode
+        # therefore writes no row twice, and pad-lane writes land on
         # dead rows that the next admission's prefill fully overwrites.
         # With the prefix cache on, free rows may be resident donors whose
         # rows are still valuable: borrow non-donor rows first, and evict

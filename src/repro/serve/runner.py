@@ -12,10 +12,12 @@ exposes exactly the operations the engine composes:
   ``slot_idx``; returns ``(last_logits, ok, placed_state)``. The state is
   positional argument 3 so the engine can donate it
   (``donate_argnums=(3,)``);
-* ``decode(params, tokens, state, pos, slot_idx)`` — gather the rows
-  named by ``slot_idx``, decode one token, scatter back; returns
-  ``(logits, ok, placed_state)``. State is positional argument 2
-  (``donate_argnums=(2,)``);
+* ``decode(params, tokens, state, pos, slot_idx)`` — decode one token
+  for the rows named by ``slot_idx``; returns ``(logits, ok,
+  new_state)``. State is positional argument 2 (``donate_argnums=(2,)``).
+  Decoder runners decode in place on the donated pool, writing only each
+  row's new entries; the enc-dec runner gathers the rows, decodes and
+  scatters them back;
 * ``gather_state`` / ``place_state`` / ``reset_rows`` — row-level state
   surgery (slot compaction, scrubbing poisoned slots, restore).
 
@@ -29,12 +31,14 @@ invariance: the same request must produce bit-identical tokens at any
 bucket shape, including the unbucketed B=1 loop.
 
 **State-tree shape rules.** The state tree is an arbitrary pytree whose
-leaves each carry a slot axis. ``gather_state``/``place_state``/
-``reset_rows`` are the only code that knows which axis that is (axis 0
-for plain decoder groups, axis 1 for repeat-stacked groups and the
-enc-dec layer-stacked leaves). Snapshot/restore never inspects the tree:
-it flattens leaves generically (``serve.guard.flatten_state_tree``) and
-restores against ``init_state``'s structure and dtypes.
+leaves each carry a slot axis: axis 0 for plain decoder groups, axis 1
+for repeat-stacked groups and the enc-dec layer-stacked leaves. In the
+runners, ``gather_state``/``place_state``/``reset_rows`` know which axis
+that is; a decoder runner's in-place decode hands the pool to the model,
+whose layers index it the same way (``nn.attention.pool_index``).
+Snapshot/restore never inspects the tree: it flattens leaves generically
+(``serve.guard.flatten_state_tree``) and restores against
+``init_state``'s structure and dtypes.
 
 **Capability flags.** ``supports_prefix_cache`` declares whether state
 rows are position-sliceable (a donor's rows for positions ``[0, m)`` can
@@ -132,8 +136,9 @@ class ModelRunner:
 
 
 class DecoderRunner(ModelRunner):
-    """Runner over :class:`HybridDecoderLM` — the pre-refactor engine
-    device path, verbatim (the refactor's bit-identity oracle).
+    """Runner over :class:`HybridDecoderLM` — bit-identical to the
+    pre-refactor engine device path (the refactor's oracle); decode runs
+    in place on the slot pool.
 
     The state tree is the model's cache: a list with one dict per layer
     group; leaves carry the slot axis at 0 (plain groups) or 1
@@ -218,19 +223,21 @@ class DecoderRunner(ModelRunner):
         return out
 
     def decode(self, params, tokens, state, pos, slot_idx):
-        """Gather the slot rows named by ``slot_idx`` into a bucket-shaped
-        sub-batch, decode one token there, then scatter the updated rows
-        back into the persistent slot state. ``tokens (Bb, 1)``, ``pos
-        (Bb,)``, ``slot_idx (Bb,)`` — a pure permutation of rows, so the
-        per-slot math is identical to full-slot decode.
+        """Decode one token for the slot rows named by ``slot_idx`` in
+        place on the persistent slot state: per layer only the new K/V/pos
+        entries are written (recurrent state: the launched rows), and
+        attention reads the rows straight from the pool — no gathered
+        sub-batch, no scatter of whole rows. ``tokens (Bb, 1)``, ``pos
+        (Bb,)``, ``slot_idx (Bb,)`` distinct rows; bit-identical to
+        gathering the rows, decoding them and scattering them back.
 
-        Returns ``(logits, ok, placed_state)`` — ``ok`` is the same
-        per-row finiteness flag as ``prefill`` (no extra executable)."""
-        sub = self.gather_state(state, slot_idx)
-        logits, new_sub = self.model.decode_step(params, tokens, sub, pos,
-                                                 moe_no_drop=True)
+        Returns ``(logits, ok, state)`` — ``ok`` is the same per-row
+        finiteness flag as ``prefill`` (no extra executable)."""
+        logits, state = self.model.decode_step(params, tokens, state, pos,
+                                               moe_no_drop=True,
+                                               slot_idx=slot_idx)
         ok = jnp.isfinite(logits).all(axis=-1)
-        return logits, ok, self.place_state(state, new_sub, slot_idx)
+        return logits, ok, state
 
     @jax.named_scope("kv_move")
     def gather_state(self, src, idx):

@@ -75,6 +75,16 @@ def _cfg_encdec():
                        n_layers=2, n_enc_layers=2, enc_seq=8, swm=_swm())
 
 
+def _cfg_ring():
+    """A 4-entry ``attn_local`` ring beside a full-length layer, repeat-
+    stacked: decode past position 4 wraps the ring."""
+    return ModelConfig(**_BASE, n_layers=4, sliding_window=4, swm=_swm(),
+                       groups=(LayerGroup(layers=(
+                           LayerSpec(mixer="attn_local", ffn="dense"),
+                           LayerSpec(mixer="attn", ffn="dense"),),
+                           repeat=2),))
+
+
 FAMILY_CFGS = {
     "attn": _cfg_attn,
     "rwkv": _cfg_rwkv,
@@ -82,6 +92,7 @@ FAMILY_CFGS = {
     "jamba": _cfg_jamba,
     "moe": _cfg_moe,
     "encdec": _cfg_encdec,
+    "ring": _cfg_ring,
 }
 
 EXPECTED_RUNNER = {
@@ -91,6 +102,7 @@ EXPECTED_RUNNER = {
     "jamba": RecurrentRunner,
     "moe": DecoderRunner,
     "encdec": EncDecRunner,
+    "ring": DecoderRunner,
 }
 
 
@@ -194,6 +206,73 @@ def test_decoder_family_matches_prerefactor_reference():
             pos += 1
         ref.append(out)
     assert outs == ref
+
+
+# ---------------------------------------------------------------------------
+# In-place decode on the slot pool == gather -> decode_step -> place
+# ---------------------------------------------------------------------------
+
+#: (slot rows launched, rows prefilled) over a 6-slot pool. "permuted":
+#: four of six live rows, out of order; "pad_lanes": three live rows and
+#: a pad lane on a free (never prefilled) row, as the engine borrows them.
+_LAUNCHES = {
+    "permuted": ([4, 1, 5, 0], [0, 1, 2, 3, 4, 5]),
+    "pad_lanes": ([2, 0, 3, 5], [0, 1, 2, 3]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LAUNCHES))
+@pytest.mark.parametrize("family", [f for f in FAMILY_CFGS if f != "encdec"])
+def test_decode_in_place_matches_gather_place(family, case):
+    """``runner.decode`` on the whole pool returns the same logits, ``ok``
+    and whole pool, bit for bit, as gathering the launched rows, running
+    ``model.decode_step`` on them and scattering them back — across
+    repeat-stacked and plain groups, recurrent state, and a ring that
+    wraps (prompts up to 9 tokens, then 5 steps, on a 4-entry ring)."""
+    from repro.kernels.block_circulant.plan import freeze_params
+
+    cfg, model, params = _built(family)
+    runner = make_runner(model, cfg, 16)
+    assert isinstance(runner, EXPECTED_RUNNER[family])
+    params = freeze_params(runner.specs(), params)
+    idx_list, filled = _LAUNCHES[case]
+    n_slots, Sb = 6, 10
+    lens = np.array([3, 7, 5, 9, 2, 8])[:len(filled)]
+    rng = np.random.default_rng(3)
+    toks = np.zeros((len(filled), Sb), np.int32)
+    posn = np.full((len(filled), Sb), -1, np.int32)
+    for j, L in enumerate(lens):
+        toks[j, Sb - L:] = rng.integers(1, cfg.vocab, size=L)
+        posn[j, Sb - L:] = np.arange(L)
+    _, _, pool = jax.jit(runner.prefill)(
+        params, jnp.asarray(toks), jnp.asarray(posn),
+        runner.init_state(n_slots), jnp.asarray(filled, jnp.int32))
+
+    def reference(params, tokens, state, pos, slot_idx):
+        sub = runner.gather_state(state, slot_idx)
+        logits, sub = model.decode_step(params, tokens, sub, pos,
+                                        moe_no_drop=True)
+        ok = jnp.isfinite(logits).all(axis=-1)
+        return logits, ok, runner.place_state(state, sub, slot_idx)
+
+    in_place, ref = jax.jit(runner.decode), jax.jit(reference)
+    idx = jnp.asarray(idx_list, jnp.int32)
+    row_len = dict(zip(filled, lens))
+    pos = np.array([row_len.get(s, 0) for s in idx_list], np.int32)
+    cur = rng.integers(1, cfg.vocab, size=(len(idx_list), 1)).astype(np.int32)
+    pool_a = pool_b = pool
+    for _ in range(5):
+        args = (jnp.asarray(cur), jnp.asarray(pos), idx)
+        la, oka, pool_a = in_place(params, args[0], pool_a, *args[1:])
+        lb, okb, pool_b = ref(params, args[0], pool_b, *args[1:])
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+        np.testing.assert_array_equal(np.asarray(oka), np.asarray(okb))
+        for a, b in zip(jax.tree.leaves(pool_a), jax.tree.leaves(pool_b)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert bool(np.asarray(oka).all())
+        cur = np.argmax(np.asarray(la), axis=-1).astype(np.int32)[:, None]
+        pos = pos + 1
 
 
 # ---------------------------------------------------------------------------

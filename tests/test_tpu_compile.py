@@ -1,21 +1,31 @@
 """The block-circulant kernels compile for a TPU v5e, without one attached.
 
 The TPU compiler is asked for each kernel of the main path at qwen3-0.6b's
-projection shapes (k=128) and at k=64, for a described ``v5e:2x2`` chip.
+projection shapes (k=128) and at k=64, and for the serving decode step's
+memory, for a described ``v5e:2x2`` chip.
 The default backend stays the CPU, so every call passes
 ``interpret=False``: left to choose, the ops would pick the interpreter,
 whose executables hold no kernel. A compiled executable holds the kernel
 exactly when its text has a ``tpu_custom_call``.
 """
 
+import dataclasses
+import json
 import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import SWMConfig
+from repro.configs.registry import get_config
 from repro.kernels.block_circulant import ops
+from repro.kernels.block_circulant.plan import freeze_params
+from repro.launch.specs import build_model
+from repro.nn.module import init_params
+from repro.serve.runner import DecoderRunner
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -98,3 +108,39 @@ def test_forward_compiles_at_k64(one_chip):
     text = _compile(_fwd, one_chip, ((256, q * k), jnp.bfloat16),
                     ((p, q, k), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+def test_decode_in_place_keeps_no_pool_copy(one_chip):
+    """The serving decode step at qwen3-0.6b's widths (4 layers, a small
+    vocab, 8 slots x 2048) decodes in place on the donated slot pool: its
+    temporaries stay under 5% of the pool. Gathering the launched rows,
+    decoding them and scattering them back held three pool copies."""
+    cj = json.loads((pathlib.Path(__file__).parents[1] / "bench" / "configs"
+                     / "qwen3-0.6b.json").read_text())
+    cfg = dataclasses.replace(
+        get_config(cj["registry"]), n_layers=4, vocab=512,
+        d_model=cj["hidden_size"], n_heads=cj["num_attention_heads"],
+        n_kv_heads=cj["num_key_value_heads"], head_dim=cj["head_dim"],
+        d_ff=cj["intermediate_size"], qk_norm=cj["qk_norm"],
+        param_dtype=cj["param_dtype"], compute_dtype=cj["compute_dtype"],
+        swm=SWMConfig(block_size=cj["swm_block_size"], impl=cj["swm_impl"]))
+    slots, cache_len = 8, 2048
+    runner = DecoderRunner(build_model(cfg), cfg, cache_len)
+    specs = runner.specs()
+    params = jax.eval_shape(
+        lambda: freeze_params(specs, init_params(specs, 0)))
+    pool = jax.eval_shape(lambda: runner.init_state(slots))
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    mem = jax.jit(runner.decode, donate_argnums=(2,)).lower(
+        placed(params), tokens, placed(pool), rows, rows,
+    ).compile().memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 0.05 * pool_bytes, (
+        mem.temp_size_in_bytes, pool_bytes)
