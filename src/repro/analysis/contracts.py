@@ -19,10 +19,10 @@ serve_decode[...]       (fused shapes); plus NoFFT when the config's impl is
                         ``paper``/``freq`` impls legitimately transform
                         *activations*, so only the weight side is
                         contractual); one surface per engine bucket.
-                        Decode surfaces of attention-only, expert-free
-                        models add ScopedContractions (every contraction
-                        under a ``DECODE_SCOPES`` name; MoE routing and
-                        recurrent mixers have no scope of their own).
+                        Decode surfaces of models without recurrent
+                        mixers add ScopedContractions (every contraction
+                        under a ``DECODE_SCOPES`` name; recurrent mixers
+                        have no scope of their own).
 serve_params            QuantizedTableDtypes (engine's quantize mode).
 serve_donation          DonatedInputsAliased on the lowered decode/prefill
                         modules (engines built with ``donate=True``).
@@ -260,7 +260,7 @@ def serve_trace_jaxprs(engine) -> List[Tuple[str, Any]]:
     """
     out = []
     for Sb in engine.prompt_buckets:
-        for Bb in engine.batch_buckets:
+        for Bb in engine.prefill_batch_buckets[Sb]:
             args, kw = _serve_trace_args(engine, Bb, Sb)
             kw_leaves, kw_tree = jax.tree.flatten(kw)
             jp = jax.make_jaxpr(
@@ -292,11 +292,11 @@ def _serve_rules(engine) -> Tuple[Any, ...]:
 
 def _decode_rules(engine) -> Tuple[Any, ...]:
     """The decode surfaces' extra rules: the model step's named scopes,
-    for models whose every contraction one of them describes."""
+    for models whose every contraction one of them describes (recurrent
+    mixers have no scope of their own)."""
     from repro.serve.runner import recurrent_mixer_names
 
-    cfg = engine.cfg
-    if cfg.is_moe or recurrent_mixer_names(cfg):
+    if recurrent_mixer_names(engine.cfg):
         return ()
     return (ScopedContractions(),)
 

@@ -292,12 +292,13 @@ class NoWeightConcat:
 
 #: The model step's stable ``jax.named_scope`` names: ``circulant`` (the
 #: ``core.circulant`` projection entry points), ``attention``
-#: (``Attention.__call__``), ``kv_move`` (a runner's ``gather_state`` /
-#: ``place_state``, and ``Attention._write_cache``: in decode, only the
-#: new entries written into the slot pool)
-#: and ``head`` (the logits head). A device op's profiler
-#: name carries them as path segments; the innermost one names its layer.
-DECODE_SCOPES = ("circulant", "attention", "kv_move", "head")
+#: (``Attention.__call__``, ``MLAttention.__call__``), ``kv_move`` (a
+#: runner's ``gather_state`` / ``place_state``, and ``write_cache``: in
+#: decode, only the new entries written into the slot pool), ``head``
+#: (the logits head) and ``moe`` (``MoE.__call__``: router, top-k,
+#: dispatch, combine). A device op's profiler name carries them as path
+#: segments; the innermost one names its layer.
+DECODE_SCOPES = ("circulant", "attention", "kv_move", "head", "moe")
 
 
 class ScopedContractions:

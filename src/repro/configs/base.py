@@ -50,8 +50,10 @@ class SWMConfig:
 class LayerSpec:
     """One decoder layer's composition within a scan group.
 
-    mixer: 'attn' | 'attn_local' | 'mamba' | 'rwkv'
-    ffn:   'dense' | 'moe' | 'dense+moe' (arctic parallel residual) | 'none'
+    mixer: 'attn' | 'attn_local' | 'mla' (latent attention) | 'mamba' |
+           'rwkv'
+    ffn:   'dense' | 'moe' | 'dense+moe' (a dense FFN beside the experts:
+           arctic's parallel residual, deepseek's shared experts) | 'none'
     """
 
     mixer: str = "attn"
@@ -64,6 +66,44 @@ class LayerGroup:
 
     layers: Tuple[LayerSpec, ...]
     repeat: int
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1).
+
+    Keys and values of every head are expanded from one cached latent of
+    ``kv_lora_rank`` plus one ``qk_rope_head_dim`` rotary key shared by all
+    heads; a query head is ``qk_nope_head_dim + qk_rope_head_dim`` wide and
+    a value head ``v_head_dim``. Queries are projected directly (no
+    ``q_lora_rank``)."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one cached position: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling as ``DeepseekV2YarnRotaryEmbedding`` defines it
+    (``rope_scaling`` of type ``yarn``)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -92,12 +132,18 @@ class ModelConfig:
     logit_softcap: float = 0.0
     flash_q_chunk: int = 512
     flash_kv_chunk: int = 1024
+    mla: Optional[MLAConfig] = None     # latent attention on every layer
+    yarn: Optional[YarnConfig] = None   # rotary scaling (mla layers)
     # ffn / moe
     n_experts: int = 0
     n_experts_per_token: int = 0
     d_ff_expert: int = 0
     moe_every: int = 1                  # jamba: MoE on every Nth layer
     dense_residual_ffn: bool = False    # arctic: dense FFN in parallel w/ MoE
+    d_ff_shared: int = 0                # that FFN's width (0: d_ff)
+    first_dense_layers: int = 0         # deepseek: leading dense-FFN layers
+    moe_norm_topk: bool = True          # renormalise the top-k gates
+    moe_routed_scale: float = 1.0       # factor on un-normalised gates
     capacity_factor: float = 1.25
     # mamba (hybrid)
     attn_every: int = 0                 # jamba: attention every Nth layer
@@ -153,7 +199,9 @@ class ModelConfig:
             return self.groups
         specs = []
         for i in range(self.n_layers):
-            if self.attn_every > 0:
+            if self.mla is not None:
+                mixer = "mla"
+            elif self.attn_every > 0:
                 mixer = "attn" if i % self.attn_every == self.attn_offset else "mamba"
             elif self.local_global_pattern > 0:
                 period = self.local_global_pattern + 1
@@ -162,12 +210,17 @@ class ModelConfig:
                 mixer = "attn_local"        # no pattern -> all-local
             else:
                 mixer = "attn"
-            if self.is_moe and (i % self.moe_every == self.moe_every - 1):
+            if (self.is_moe and i >= self.first_dense_layers
+                    and i % self.moe_every == self.moe_every - 1):
                 ffn = "dense+moe" if self.dense_residual_ffn else "moe"
             else:
                 ffn = "dense"
             specs.append(LayerSpec(mixer=mixer, ffn=ffn))
-        return _group_layers(tuple(specs))
+        # leading dense layers are their own group, so the rest stays one
+        # scan (one pattern over all layers would find no period)
+        k = self.first_dense_layers
+        return _group_layers(tuple(specs[:k])) + _group_layers(
+            tuple(specs[k:]))
 
 
 def _group_layers(specs: Tuple[LayerSpec, ...]) -> Tuple[LayerGroup, ...]:
@@ -178,6 +231,8 @@ def _group_layers(specs: Tuple[LayerSpec, ...]) -> Tuple[LayerGroup, ...]:
     own group(s).
     """
     n = len(specs)
+    if not n:
+        return ()
     for period in range(1, n + 1):
         pattern = specs[:period]
         if all(specs[i] == pattern[i % period] for i in range(n)):

@@ -13,6 +13,7 @@ ARCHS: Dict[str, str] = {
     "gemma3-27b": "repro.configs.gemma3_27b",
     "qwen3-0.6b": "repro.configs.qwen3_0_6b",
     "deepseek-7b": "repro.configs.deepseek_7b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "internlm2-20b": "repro.configs.internlm2_20b",
     "arctic-480b": "repro.configs.arctic_480b",
     "qwen3-moe-235b-a22b": "repro.configs.qwen3_moe_235b",

@@ -74,6 +74,22 @@ def _layer_terms(cfg: ModelConfig, spec: LayerSpec, tokens: int,
             kvb += 2 * (tokens * eff_kv) * HKV * hd * BF16
         else:
             kvb += 2 * tokens * HKV * hd * BF16        # write-once
+    elif spec.mixer == "mla":
+        m = cfg.mla
+        r, dn, dv = m.kv_lora_rank, m.qk_nope_head_dim, m.v_head_dim
+        shapes = ((HQ * m.qk_head_dim, d), (m.latent_dim, d),
+                  (HQ * (dn + dv), r), (d, HQ * dv))
+        for mo, mi in shapes:
+            f += _proj_flops(cfg, tokens, mo, mi, "attn")
+            b += _proj_bytes(cfg, mo, mi, "attn")
+        # decode scores the latent and the rope key and sums latents
+        # (absorbed); prefill attends with expanded heads
+        per_pos = (2 * HQ * (2 * r + m.qk_rope_head_dim) if kind == "decode"
+                   else 2 * HQ * (m.qk_head_dim + dv))
+        causal_f = 0.5 if (kind != "decode" and s_q == s_kv) else 1.0
+        f += tokens * s_kv * per_pos * causal_f
+        kvb += (tokens * s_kv if kind == "decode" else tokens) \
+            * m.latent_dim * BF16
     elif spec.mixer == "mamba":
         di, ds = cfg.mamba_expand * d, cfg.mamba_d_state
         dtr = cfg.mamba_dt_rank or max(1, d // 16)
@@ -103,10 +119,12 @@ def _layer_terms(cfg: ModelConfig, spec: LayerSpec, tokens: int,
               + _proj_bytes(cfg, d, cfg.d_ff, "ffn"))
     else:
         if spec.ffn in ("dense", "dense+moe"):
-            f += 2 * _proj_flops(cfg, tokens, cfg.d_ff, d, "ffn")
-            f += _proj_flops(cfg, tokens, d, cfg.d_ff, "ffn")
-            b += 2 * _proj_bytes(cfg, cfg.d_ff, d, "ffn") \
-                + _proj_bytes(cfg, d, cfg.d_ff, "ffn")
+            dff = ((cfg.d_ff_shared or cfg.d_ff) if spec.ffn == "dense+moe"
+                   else cfg.d_ff)
+            f += 2 * _proj_flops(cfg, tokens, dff, d, "ffn")
+            f += _proj_flops(cfg, tokens, d, dff, "ffn")
+            b += 2 * _proj_bytes(cfg, dff, d, "ffn") \
+                + _proj_bytes(cfg, d, dff, "ffn")
         if spec.ffn in ("moe", "dense+moe"):
             E, T = cfg.n_experts, cfg.n_experts_per_token
             dff = cfg.d_ff_expert or cfg.d_ff

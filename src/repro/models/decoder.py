@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import LayerGroup, LayerSpec, ModelConfig
-from repro.nn.attention import (Attention, init_kv_cache, pool_index,
-                                pool_rows)
+from repro.nn.attention import (Attention, MLAttention, init_kv_cache,
+                                init_mla_cache, pool_index, pool_rows)
 from repro.nn.ffn import SwiGLU
 from repro.nn.layers import Embedding, RMSNorm
 from repro.nn.moe import MoE
@@ -81,6 +81,8 @@ class HybridDecoderLM:
         if spec.mixer == "attn_local":
             return Attention(cfg, local=True, stack=stack,
                              prefix_len=cfg.n_img_tokens)
+        if spec.mixer == "mla":
+            return MLAttention(cfg, stack=stack)
         if spec.mixer == "mamba":
             return Mamba(cfg, stack=stack)
         if spec.mixer == "rwkv":
@@ -94,7 +96,9 @@ class HybridDecoderLM:
             out["dense"] = RWKV6ChannelMix(cfg, stack=stack)
             return out
         if spec.ffn in ("dense", "dense+moe"):
-            out["dense"] = SwiGLU(d_model=cfg.d_model, d_ff=cfg.d_ff,
+            d_ff = (cfg.d_ff_shared or cfg.d_ff) if spec.ffn == "dense+moe" \
+                else cfg.d_ff
+            out["dense"] = SwiGLU(d_model=cfg.d_model, d_ff=d_ff,
                                   swm=cfg.swm, stack=stack,
                                   dtype=cfg.param_dtype)
         if spec.ffn in ("moe", "dense+moe"):
@@ -103,6 +107,8 @@ class HybridDecoderLM:
                              n_experts=cfg.n_experts,
                              top_k=cfg.n_experts_per_token,
                              capacity_factor=cfg.capacity_factor,
+                             norm_topk=cfg.moe_norm_topk,
+                             routed_scale=cfg.moe_routed_scale,
                              swm=cfg.swm, stack=stack, dtype=cfg.param_dtype)
         return out
 
@@ -169,6 +175,8 @@ class HybridDecoderLM:
         if lspec.mixer == "attn_local":
             return init_kv_cache(batch, local_attn_cache_len(cfg, cache_len),
                                  cfg.n_kv_heads, cfg.head_dim, cfg.dtype)
+        if lspec.mixer == "mla":
+            return init_mla_cache(batch, cache_len, cfg.mla, cfg.dtype)
         if lspec.mixer == "mamba":
             m = Mamba(cfg)
             return init_mamba_cache(batch, m.d_inner, cfg.mamba_d_state,
@@ -189,7 +197,7 @@ class HybridDecoderLM:
         writes and reads it in place; recurrent state, small and rewritten
         whole every step, is taken at ``slot_idx``, stepped and put back."""
         cfg = self.cfg
-        attn = lspec.mixer in ("attn", "attn_local")
+        attn = lspec.mixer in ("attn", "attn_local", "mla")
         if slot_idx is not None and not attn:
             rows = _take_rows(cache, slot_idx, layer)
             x, rows, aux = self._apply_layer(lspec, stack, p, x, positions,

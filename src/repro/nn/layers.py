@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -14,6 +15,8 @@ __all__ = [
     "RMSNorm",
     "Embedding",
     "rotary",
+    "rope_frequencies",
+    "yarn_mscale",
     "apply_rope",
     "causal_mask",
     "sliding_window_mask",
@@ -82,13 +85,52 @@ class Embedding:
 # ---------------------------------------------------------------------------
 
 
-def rotary(positions: jax.Array, head_dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
-    """positions (...,S) -> cos/sin (...,S, head_dim/2), f32."""
-    freqs = theta ** (
-        -jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    )
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 m ln(s) + 1`` (1 for
+    ``s <= 1``)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def rope_frequencies(head_dim: int, theta: float, yarn=None) -> jax.Array:
+    """The ``head_dim / 2`` rotary frequencies, f32. With a
+    :class:`~repro.configs.base.YarnConfig` they are YaRN's blend of the
+    base frequencies (high ones, kept) and the base over ``factor`` (low
+    ones, interpolated), along a linear ramp over the dims between the
+    correction dims of ``beta_fast`` and ``beta_slow`` rotations in
+    ``original_max_position`` positions (``DeepseekV2YarnRotaryEmbedding``).
+    """
+    base = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                     / head_dim)
+    if yarn is None:
+        return base
+
+    def corr_dim(rot):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    return base / yarn.factor * ramp + base * (1.0 - ramp)
+
+
+def rotary(positions: jax.Array, head_dim: int, theta: float,
+           yarn=None) -> Tuple[jax.Array, jax.Array]:
+    """positions (...,S) -> cos/sin (...,S, head_dim/2), f32. With YaRN
+    both carry the ratio of its ``mscale`` to ``mscale_all_dim`` factors."""
+    freqs = rope_frequencies(head_dim, theta, yarn)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    c, s = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            c, s = c * m, s * m
+    return c, s
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

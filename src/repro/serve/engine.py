@@ -26,10 +26,11 @@ of that split, applied at three levels:
 
 * **Bucketed shapes** — jit recompilation is bounded by rounding every
   launch to a bucket grid: prefill batch sizes come from ``batch_buckets``
-  (powers of two up to the slot count), prompt lengths round up to
-  ``prompt_buckets``, and *decode* launches compact the active slots into
-  the smallest ``decode_buckets`` batch that holds them. A full engine
-  lifetime therefore compiles at most
+  (powers of two up to the slot count; for a prompt bucket only those
+  within the runner's ``max_prefill_tokens``, ``prefill_batch_buckets``),
+  prompt lengths round up to ``prompt_buckets``, and *decode* launches
+  compact the active slots into the smallest ``decode_buckets`` batch that
+  holds them. A full engine lifetime therefore compiles at most
   ``len(batch_buckets) · len(prompt_buckets)`` prefill executables plus
   ``len(decode_buckets)`` decode executables (``prewarm()`` compiles them
   all up front). The wave baseline instead recompiles for every distinct
@@ -1078,6 +1079,11 @@ class ServeEngine:
         self.prompt_buckets = validate_buckets(
             "prompt_buckets", prompt_buckets, self.cache_len)
         self.batch_buckets = pow2_buckets(1, self.batch)
+        cap = self.runner.max_prefill_tokens
+        self.prefill_batch_buckets = {
+            Sb: [b for b in self.batch_buckets
+                 if not cap or b * Sb <= max(cap, Sb)]
+            for Sb in self.prompt_buckets}
         if decode_buckets is None:
             decode_buckets = self.batch_buckets
         # any active-slot count must map to a bucket -> batch terminates
@@ -1140,7 +1146,7 @@ class ServeEngine:
     @property
     def max_prefill_variants(self) -> int:
         """Upper bound on distinct prefill executables over the lifetime."""
-        return len(self.batch_buckets) * len(self.prompt_buckets)
+        return sum(map(len, self.prefill_batch_buckets.values()))
 
     @property
     def max_decode_variants(self) -> int:
@@ -1645,7 +1651,8 @@ class ServeEngine:
                 by_bucket.setdefault(Sb, []).append(rid)
         for Sb in sorted(by_bucket):
             rids_b = by_bucket[Sb]
-            for Bb in batch_split(len(rids_b), self.batch_buckets):
+            for Bb in batch_split(len(rids_b),
+                                  self.prefill_batch_buckets[Sb]):
                 chunk, rids_b = rids_b[:Bb], rids_b[Bb:]
                 with TraceAnnotation("serve.admit", step=self._step_count):
                     slots, toks, pos, prompts, kw = self._chunk_inputs(
@@ -1818,13 +1825,16 @@ class ServeEngine:
             raise StructuralContractError(violations)
         return violations
 
-    def prewarm(self, audit: bool = False) -> int:
+    def prewarm(self, audit: bool = False,
+                max_prompt_bucket: Optional[int] = None) -> int:
         """Compile every (batch-bucket, prompt-bucket) prefill executable
         plus every decode-bucket executable up front, so steady-state
         serving never recompiles. Possible precisely because the bucket
         grid is finite — the wave baseline has no analogue (one executable
         per distinct wave length it happens to see). Returns the number of
-        live executables.
+        live executables. ``max_prompt_bucket`` leaves out the prompt
+        buckets above it, for traffic known to send no longer prompt (a
+        longer one would compile when it first arrives).
 
         ``audit=True`` gates compilation on the structural contracts: the
         bucketed executables are traced and audited first (``audit()``),
@@ -1852,7 +1862,9 @@ class ServeEngine:
             for s in range(self.batch):
                 self._index_drop_slot(s)
         for Sb in self.prompt_buckets:
-            for Bb in self.batch_buckets:
+            if max_prompt_bucket is not None and Sb > max_prompt_bucket:
+                continue
+            for Bb in self.prefill_batch_buckets[Sb]:
                 toks = jnp.zeros((Bb, Sb), jnp.int32)
                 # all-pad rows (every position negative): fully masked,
                 # mathematically defined, and shape-identical to real traffic

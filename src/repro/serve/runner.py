@@ -47,7 +47,8 @@ seed another request). Full-length KV caches are; recurrent state is not
 per-position rows to copy), nor are short local-attention rings (donor
 rows past the window are overwritten). ``prefix_cache_unsupported_reason``
 carries the actionable message the engine raises. ``min_cache_len``
-bounds ``cache_len`` from below; ``requires_extra`` marks families whose
+bounds ``cache_len`` from below; ``max_prefill_tokens`` bounds a prefill
+launch's rows x prompt bucket; ``requires_extra`` marks families whose
 requests carry per-request conditioning (the enc-dec encoder frames).
 """
 
@@ -62,7 +63,22 @@ import numpy as np
 from repro.configs.base import ModelConfig
 
 __all__ = ["ModelRunner", "DecoderRunner", "RecurrentRunner",
-           "EncDecRunner", "make_runner", "recurrent_mixer_names"]
+           "EncDecRunner", "make_runner", "recurrent_mixer_names",
+           "PREFILL_ACTIVATION_BYTES", "prefill_token_bytes"]
+
+#: Device bytes the activations of one prefill launch may take, beside the
+#: weights and the slot pool: a decoder's ``max_prefill_tokens`` is this
+#: over :func:`prefill_token_bytes`.
+PREFILL_ACTIVATION_BYTES = 2_500_000_000
+
+
+def prefill_token_bytes(cfg: ModelConfig) -> int:
+    """Device bytes one token of a prefill launch holds at its peak: about
+    six float32 copies of the model's widest hidden (an FFN's gate and up
+    projections and their frequency transforms; 23 bytes per unit of
+    width measured at DeepSeek-V2-Lite's 10,944: 3.26 GB of temporaries
+    at 8 x 1024 tokens, AOT on a described v5e)."""
+    return 24 * max(cfg.d_ff, cfg.d_model)
 
 
 def recurrent_mixer_names(cfg: ModelConfig) -> Tuple[str, ...]:
@@ -85,6 +101,9 @@ class ModelRunner:
     prefix_cache_unsupported_reason: str = ""
     #: smallest servable cache_len
     min_cache_len: int = 1
+    #: most tokens (rows x prompt bucket) one prefill launch may hold; the
+    #: engine launches fewer rows of a longer bucket (0: no limit)
+    max_prefill_tokens: int = 0
     #: whether requests must carry per-request conditioning (Request.extra)
     requires_extra: bool = False
 
@@ -153,9 +172,18 @@ class DecoderRunner(ModelRunner):
             1 if g.repeat > 1 else 0 for g in cfg.layer_groups()
         )
         self.supports_prefix_cache = True
+        self.max_prefill_tokens = (PREFILL_ACTIVATION_BYTES
+                                   // prefill_token_bytes(cfg))
         from repro.models.decoder import local_attn_cache_len
         for group in cfg.layer_groups():
             for lspec in group.layers:
+                if lspec.mixer == "mla":
+                    self.supports_prefix_cache = False
+                    self.prefix_cache_unsupported_reason = (
+                        "prefix_cache seeds a prefill launch with a donor's "
+                        "rows, but 'mla' layers prefill in the expanded "
+                        "form over the launch's own positions only: the "
+                        "copied head would not be attended")
                 if lspec.mixer == "attn_local":
                     ring = local_attn_cache_len(cfg, self.cache_len)
                     if ring < self.cache_len:

@@ -235,9 +235,11 @@ def test_scoped_contractions_rule_fires_outside_every_scope():
 
 
 def test_decode_surfaces_carry_the_scope_rule():
-    """The dense decoders' decode surfaces are held to the scopes (the
-    engine audit passes, so every contraction of theirs is scoped); MoE and
-    recurrent families have contractions no scope names, and are not."""
+    """The attention and expert decoders' decode surfaces are held to the
+    scopes (the engine audit passes, so every contraction of theirs is
+    scoped: the router and experts under ``moe``, latent attention under
+    ``attention``); recurrent families have contractions no scope names,
+    and are not."""
     from repro.analysis.contracts import (_decode_rules, _smoke_engine,
                                           audit_engine)
     from repro.configs.registry import get_smoke
@@ -249,11 +251,11 @@ def test_decode_surfaces_carry_the_scope_rule():
         model = build_model(cfg)
         return _smoke_engine(model, cfg, init_params(model.specs(), 0), "off")
 
-    eng = engine("qwen3-0.6b")
-    assert [type(r) for r in _decode_rules(eng)] == [ScopedContractions]
-    assert audit_engine(eng) == []
-    for arch in ("qwen3-moe-235b-a22b", "rwkv6-7b"):
-        assert _decode_rules(engine(arch)) == ()
+    for arch in ("qwen3-0.6b", "qwen3-moe-235b-a22b", "deepseek-v2-lite"):
+        eng = engine(arch)
+        assert [type(r) for r in _decode_rules(eng)] == [ScopedContractions]
+        assert audit_engine(eng) == []
+    assert _decode_rules(engine("rwkv6-7b")) == ()
 
 
 def test_launch_budget_points_at_excess_launch():

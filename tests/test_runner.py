@@ -70,6 +70,22 @@ def _cfg_moe():
                            repeat=1),))
 
 
+def _cfg_mla():
+    """Latent attention on every layer, a leading dense layer, then routed
+    and shared experts with un-normalised gates (DeepSeek-V2's block)."""
+    from repro.configs.base import MLAConfig, YarnConfig
+
+    return ModelConfig(**_BASE, n_layers=3, n_experts=8,
+                       n_experts_per_token=3, d_ff_expert=16,
+                       dense_residual_ffn=True, d_ff_shared=32,
+                       first_dense_layers=1, moe_norm_topk=False,
+                       mla=MLAConfig(kv_lora_rank=16, qk_nope_head_dim=8,
+                                     qk_rope_head_dim=8, v_head_dim=8),
+                       yarn=YarnConfig(factor=4.0, original_max_position=16,
+                                       mscale=0.707, mscale_all_dim=0.707),
+                       swm=_swm())
+
+
 def _cfg_encdec():
     return ModelConfig(**{**_BASE, "n_kv_heads": 2}, family="encdec",
                        n_layers=2, n_enc_layers=2, enc_seq=8, swm=_swm())
@@ -91,6 +107,7 @@ FAMILY_CFGS = {
     "mamba": _cfg_mamba,
     "jamba": _cfg_jamba,
     "moe": _cfg_moe,
+    "mla": _cfg_mla,
     "encdec": _cfg_encdec,
     "ring": _cfg_ring,
 }
@@ -101,6 +118,7 @@ EXPECTED_RUNNER = {
     "mamba": RecurrentRunner,
     "jamba": RecurrentRunner,
     "moe": DecoderRunner,
+    "mla": DecoderRunner,
     "encdec": EncDecRunner,
     "ring": DecoderRunner,
 }
@@ -163,7 +181,7 @@ def _b1_oracle(runner, params, reqs, cache_len):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("family", ["rwkv", "mamba", "jamba", "moe",
+@pytest.mark.parametrize("family", ["rwkv", "mamba", "jamba", "moe", "mla",
                                     "encdec"])
 def test_bucketed_matches_b1(family):
     cfg, model, params = _built(family)
@@ -346,6 +364,10 @@ def test_prefix_cache_gated_on_capability():
     cfg_e, model_e, params_e = _built("encdec")
     with pytest.raises(ValueError, match="prefix_cache"):
         ServeEngine(model_e, cfg_e, params_e, batch=2, cache_len=32,
+                    prefix_cache=True)
+    cfg_m, model_m, params_m = _built("mla")
+    with pytest.raises(ValueError, match="'mla' layers"):
+        ServeEngine(model_m, cfg_m, params_m, batch=2, cache_len=32,
                     prefix_cache=True)
 
 

@@ -144,3 +144,50 @@ def test_decode_in_place_keeps_no_pool_copy(one_chip):
     assert mem.alias_size_in_bytes == pool_bytes
     assert mem.temp_size_in_bytes < 0.05 * pool_bytes, (
         mem.temp_size_in_bytes, pool_bytes)
+
+
+def test_mla_serving_temporaries(one_chip):
+    """DeepSeek-V2-Lite at its published widths and depth (a small vocab),
+    32 slots x 4096: decode keeps its temporaries under 5% of the donated
+    latent pool (a layout that copied the pool per write, or a read that
+    fetched a layer's rows whole, would not). The prefill launches the
+    engine makes hold at most ``max_prefill_tokens`` = 9,518 tokens: the
+    longest bucket's, 8 x 1024, keeps its temporaries under 6 GB (a
+    dispatch that ran every expert over the launch's tokens would need
+    (64, 8192, 2048) buffers and its expert hiddens), and the one of the
+    most rows, 32 x 256 (a copy of the whole pool), fits one 16 GB v5e
+    beside the pool and the weights with 2 GB to spare."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), vocab=512)
+    slots, cache_len = 32, 4096
+    runner = DecoderRunner(build_model(cfg), cfg, cache_len)
+    specs = runner.specs()
+    params = jax.eval_shape(
+        lambda: freeze_params(specs, init_params(specs, 0)))
+    pool = jax.eval_shape(lambda: runner.init_state(slots))
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+    pool_bytes = nbytes(pool)
+    mem = jax.jit(runner.decode, donate_argnums=(2,)).lower(
+        placed(params), sds((slots, 1)), placed(pool), sds((slots,)),
+        sds((slots,))).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 0.05 * pool_bytes, (
+        mem.temp_size_in_bytes, pool_bytes)
+    assert runner.max_prefill_tokens == 9518
+    for (B, S), limit in (((8, 1024), 6e9),
+                          ((32, 256), 14e9 - pool_bytes - nbytes(params))):
+        assert B * S <= runner.max_prefill_tokens
+        mem = jax.jit(runner.prefill, donate_argnums=(3,)).lower(
+            placed(params), sds((B, S)), sds((B, S)), placed(pool),
+            sds((B,))).compile().memory_analysis()
+        assert mem.alias_size_in_bytes == pool_bytes
+        assert mem.temp_size_in_bytes < limit, (B, S, mem.temp_size_in_bytes)
