@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.latent_attention import latent_decode_attention
 from repro.nn.layers import RMSNorm, apply_rope, rotary, yarn_mscale
 from repro.nn.linear import Linear
 
@@ -454,7 +455,7 @@ def write_cache(cache, new, positions, slot_idx=None, layer=None):
 # ---------------------------------------------------------------------------
 
 
-#: Positions per chunk of an absorbed decode step's read of the cache.
+#: Positions per block of an absorbed decode step's read of the cache.
 MLA_DECODE_CHUNK = 512
 
 
@@ -622,10 +623,10 @@ class MLAttention:
         """One query position against the cached ``[c, k_pe]`` of rows
         ``slot_idx`` (all rows without it): ``[Wuk^T q_nope, q_pe]`` scores
         the latent and the shared key in one product, and ``Wuv`` expands
-        the weighted latent. The cache is read in chunks of
-        :data:`MLA_DECODE_CHUNK` positions under a running softmax, so a
-        chunk of the launched rows (read twice: scores, then values) is
-        small enough to stay on chip instead of being gathered whole."""
+        the weighted latent. The cache is read by
+        :func:`~repro.kernels.latent_attention.latent_decode_attention`:
+        each row's filled blocks of :data:`MLA_DECODE_CHUNK` positions,
+        once, straight from the pool."""
         m = self.dims
         r, dn, dv = m.kv_lora_rank, m.qk_nope_head_dim, m.v_head_dim
         B, _, H, _ = q_nope.shape
@@ -663,43 +664,10 @@ class MLAttention:
              jnp.zeros((B, H, W - m.latent_dim), jnp.float32)], -1
         ).astype(dtype)
         T = cache["pos"].shape[-1]
-        tc = MLA_DECODE_CHUNK if T % MLA_DECODE_CHUNK == 0 else T
-
-        def rows(name, t0):
-            """Positions ``[t0, t0 + tc)`` of the launched rows, sliced
-            from the layer in one piece."""
-            a = cache[name]
-            lead = 0 if layer is None else 1
-            start = [layer if lead else 0] + [0] * (a.ndim - 1)
-            size = [1 if lead else a.shape[0], *a.shape[1:]]
-            start[1 + lead], size[1 + lead] = t0, tc
-            a = jax.lax.dynamic_slice(a, start, size)
-            a = a[0] if lead else a
-            return a if slot_idx is None else a[slot_idx]
-
-        def chunk(i, carry):
-            mx, den, acc = carry
-            t0 = i * tc
-            lat = rows("latent", t0)
-            s = jnp.einsum("bhl,btl->bht", qa, lat,
-                           preferred_element_type=jnp.float32) * self.scale
-            s = s + _mask_bias(q_pos, rows("pos", t0), causal=True,
-                               window=0, prefix_len=0)
-            m_new = jnp.maximum(mx, s.max(-1))
-            pr = jnp.exp(s - m_new[..., None])
-            corr = jnp.exp(mx - m_new)
-            acc = acc * corr[..., None] + jnp.einsum(
-                "bht,btr->bhr", pr.astype(dtype), lat[..., :r],
-                preferred_element_type=jnp.float32)
-            return m_new, den * corr + pr.sum(-1), acc
-
-        # a loop, not an unrolled one: the compiler would otherwise fetch
-        # every chunk of the layer at once, a copy of its rows
-        mx, den, acc = jax.lax.fori_loop(0, T // tc, chunk, (
-            jnp.full((B, H), -jnp.inf, jnp.float32),
-            jnp.zeros((B, H), jnp.float32),
-            jnp.zeros((B, H, r), jnp.float32)))
-        o_lat = acc / jnp.maximum(den, 1e-30)[..., None]
+        o_lat = latent_decode_attention(
+            qa, cache["latent"], cache["pos"], q_pos[:, 0], slot_idx, layer,
+            r=r, scale=self.scale,
+            block=MLA_DECODE_CHUNK if T % MLA_DECODE_CHUNK == 0 else T)
         if kb.is_circulant:
             o = irfft(jnp.einsum("bhjf,hijf->bhif", rfft(o_lat), wv))
         else:

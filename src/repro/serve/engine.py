@@ -857,6 +857,7 @@ class EngineStats:
     padded_prompt_tokens: int = 0          # bucket-padding waste
     slot_steps_active: int = 0             # Σ over decode steps of active slots
     decode_rows: int = 0                   # Σ over decode steps of rows launched
+    decode_ctx_positions: int = 0          # Σ over launched rows of filled positions
     prefix_lookups: int = 0                # admissions probed against the index
     prefix_hits: int = 0                   # admissions seeded from a donor
     prefill_tokens_saved: int = 0          # Σ matched prefix tokens never rerun
@@ -1760,6 +1761,10 @@ class ServeEngine:
             if n == 0:
                 return
             Bb, idx = self._decode_rows(act)
+            pos = self._slot_pos[idx]
+            # cache positions a read bounded per row needs: the row's
+            # filled ring slots, the new entry's included
+            ctx = int(np.clip(pos + 1, 0, self.cache_len).sum())
         # wrapped launch with ONE retry for transient (pre-launch) faults:
         # the injector's fired-set guarantees a scheduled fault does not
         # refire, so the retry runs the same launch with intact buffers. A
@@ -1772,11 +1777,11 @@ class ServeEngine:
                                 [self._slot_req[int(s)] for s in act])
                 with TraceAnnotation("serve.decode.launch",
                                      step=self._step_count,
-                                     rows=int(Bb)):
+                                     rows=int(Bb), ctx=ctx):
                     logits, ok, self.cache = self._decode(
                         self.params,
                         jnp.asarray(self._slot_last[idx][:, None]),
-                        self.cache, jnp.asarray(self._slot_pos[idx]),
+                        self.cache, jnp.asarray(pos),
                         jnp.asarray(idx),
                     )
                 break
@@ -1790,6 +1795,7 @@ class ServeEngine:
         self.stats.decode_steps += 1
         self.stats.slot_steps_active += int(n)
         self.stats.decode_rows += int(Bb)
+        self.stats.decode_ctx_positions += ctx
         self.stats.decode_shapes.add(int(Bb))
         self._slot_pos[act] += 1
         with TraceAnnotation("serve.decode.fetch", step=self._step_count):
@@ -2054,7 +2060,8 @@ class ServeEngine:
     _STAT_FIELDS = (
         "prefill_calls", "decode_steps", "tokens_generated",
         "requests_completed", "padded_prompt_tokens", "slot_steps_active",
-        "decode_rows", "prefix_lookups", "prefix_hits",
+        "decode_rows", "decode_ctx_positions", "prefix_lookups",
+        "prefix_hits",
         "prefill_tokens_saved", "rejected", "aborted", "expired",
         "cancelled", "recoveries", "snapshots", "launch_retries",
         "slow_steps", "prefix_spills", "prefix_adoptions",

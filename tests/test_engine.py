@@ -736,3 +736,30 @@ def test_stats_accounting(lm):
     assert 0.0 < s.tokens_per_decode_step <= eng.batch
     d = s.as_dict()
     assert d["prefill_shapes"] == sorted(s.prefill_shapes)
+
+
+def test_decode_ctx_positions_counts_each_rows_filled_positions():
+    """On the smoke DeepSeek-V2-Lite engine (latent attention, whose decode
+    reads each row up to its position), three requests of mixed lengths on
+    two slots, with a decode bucket of every size so no pad lane is
+    launched: a decode of a row at position q needs its q + 1 filled
+    positions, the new entry's included. A request of prompt p and n
+    tokens decodes at positions p .. p + n - 2 (prefill gives the first
+    token), and the counter is their sum over every launch."""
+    from repro.configs.registry import get_smoke
+
+    cfg = get_smoke("deepseek-v2-lite")
+    model = HybridDecoderLM(cfg)
+    eng = ServeEngine(model, cfg, init_params(model.specs(), 0), batch=2,
+                      cache_len=32, prompt_buckets=(16,),
+                      decode_buckets=(1, 2))
+    reqs = [Request(np.arange(p, dtype=np.int32) % cfg.vocab, max_new=n)
+            for p, n in ((5, 4), (11, 7), (2, 3))]
+    outs = eng.generate(reqs)
+    assert [len(o) for o in outs] == [4, 7, 3]
+    want = sum(q + 1 for p, n in ((5, 4), (11, 7), (2, 3))
+               for q in range(p, p + n - 1))
+    assert eng.stats.decode_ctx_positions == want == 6 + 7 + 8 + (
+        12 + 13 + 14 + 15 + 16 + 17) + 3 + 4
+    assert want < eng.stats.decode_rows * eng.cache_len
+    assert eng.stats.as_dict()["decode_ctx_positions"] == want

@@ -23,6 +23,8 @@ DECODE = ("serve.decode.prep", "serve.decode.launch", "serve.decode.fetch",
           "serve.decode.sample")
 PREFILL = ("serve.prefill.launch", "serve.prefill.fetch",
            "serve.prefill.sample")
+# (prompt length, max_new) of the traced requests
+REQS = ((5, 3), (9, 5), (4, 2))
 
 
 @pytest.fixture(scope="module")
@@ -38,7 +40,7 @@ def traced(tmp_path_factory):
     eng.prewarm()
     rng = np.random.default_rng(0)
     reqs = [Request(rng.integers(0, 48, n).astype(np.int32), max_new=m)
-            for n, m in ((5, 3), (9, 5), (4, 2))]
+            for n, m in REQS]
     d = str(tmp_path_factory.mktemp("trace"))
     admits = {}
     with jax.profiler.trace(d):
@@ -109,3 +111,16 @@ def test_prefill_spans_only_on_admitting_steps(traced):
         if n:
             assert "serve.admit" in names
     assert sum(admits.values()) == eng.stats.prefill_calls > 0
+
+
+def test_launch_span_carries_the_rows_filled_positions(traced):
+    """Each ``serve.decode.launch`` records in ``ctx`` the cache positions
+    its rows have filled, the new entry's included; over the run they sum
+    to ``EngineStats.decode_ctx_positions`` and to the hand count: a
+    request of prompt p and n tokens decodes at positions p .. p + n - 2,
+    each reading q + 1 (two slots, decode buckets 1 and 2: no pad lane)."""
+    eng, spans, admits = traced
+    ctx = [a["ctx"] for name, _, _, a in spans
+           if name == "serve.decode.launch"]
+    want = sum(q + 1 for p, n in REQS for q in range(p, p + n - 1))
+    assert sum(ctx) == eng.stats.decode_ctx_positions == want

@@ -19,6 +19,8 @@ import pytest
 from repro.configs.base import SWMConfig, YarnConfig
 from repro.configs.registry import get_config, get_smoke
 from repro.kernels.block_circulant.plan import freeze_params
+from repro.kernels.latent_attention import (blocks_needed,
+                                            latent_decode_attention)
 from repro.launch.specs import build_model
 from repro.nn import attention as attn_mod
 from repro.nn import moe as moe_mod
@@ -156,6 +158,99 @@ def test_absorbed_decode_matches_expanded_attention(kind, monkeypatch):
     np.testing.assert_allclose(np.asarray(out[:, 0]),
                                np.asarray(full[:, S - 1]), atol=1e-5)
     assert int(cache["pos"][0, S - 1]) == S - 1
+
+
+# The kernel's reads against a plain float32 masked softmax: a pool of 8
+# rows x 16 positions read in blocks of 4, 16 heads, the smoke widths.
+KT, KB, KH, KW, KR, KSCALE = 16, 4, 16, 128, 32, 0.3
+# Each pool row's last written position: rows 1, tc - 1, tc, tc + 1 and
+# T - 1 long, a wrapped ring, and two more (row 6 is free in the launch
+# below and holds a finished request's positions).
+FILLS = (0, KB - 2, KB - 1, KB, KT - 2, KT + 5, 6, 9)
+
+
+def _kernel_pool(dtype, stacked):
+    """A latent pool whose row ``n`` holds positions up to ``FILLS[n]``
+    (the ring's last T of them once wrapped; -1 in slots never written,
+    as a prefill leaves a row). Slots in whole blocks past a row's last
+    position hold NaN: a read of them would poison the row's output."""
+    rng = np.random.default_rng(7)
+    L = 3 if stacked else 1
+    lat = rng.standard_normal((L, len(FILLS), KT, KW)).astype(np.float32)
+    pos = np.full((L, len(FILLS), KT), -1, np.int32)
+    for n, f in enumerate(FILLS):
+        for p in range(max(0, f - KT + 1), f + 1):
+            pos[:, n, p % KT] = p
+        if f < KT:
+            lat[:, n, -(-(f + 1) // KB) * KB:] = np.nan
+    lat, pos = jnp.asarray(lat, dtype), jnp.asarray(pos)
+    return (lat, pos) if stacked else (lat[0], pos[0])
+
+
+def _plain_attention(qa, lat, pos, q_pos):
+    """softmax over positions 0 <= pos <= q_pos of scale * qa . lat, in
+    float32, of the latents' first KR values: the launched rows' ``lat``
+    ``(B, T, W)`` and ``pos`` ``(B, T)``."""
+    lat = np.nan_to_num(np.asarray(lat, np.float32))
+    s = np.einsum("bhw,btw->bht", np.asarray(qa, np.float32), lat) * KSCALE
+    ok = (np.asarray(pos) >= 0) & (np.asarray(pos) <= np.asarray(q_pos)[:,
+                                                                  None])
+    s = np.where(ok[:, None], s, -np.inf)
+    w = np.exp(s - s.max(-1, keepdims=True))
+    w /= w.sum(-1, keepdims=True)
+    return np.einsum("bht,btr->bhr", w, lat[..., :KR])
+
+
+@pytest.mark.parametrize("q_pos,n_b", [(-1, 0), (0, 1), (KB - 1, 1),
+                                       (KB, 2), (KT - 1, KT // KB),
+                                       (KT, KT // KB), (3 * KT + 1, KT // KB)])
+def test_blocks_needed(q_pos, n_b):
+    """A row reads the blocks that hold positions 0 .. q_pos, and the whole
+    ring once it has wrapped."""
+    assert int(blocks_needed(jnp.int32(q_pos), KT, KB)) == n_b
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("launch", ["all", "permuted"])
+def test_latent_kernel_matches_plain_softmax(dtype, atol, stacked, launch):
+    """The kernel over every row of the pool (``slot_idx`` None), or over
+    a permuted subset whose last lane is a pad lane borrowing the free row
+    6 at its stale position (the engine launches such lanes and drops
+    their outputs), against a plain float32 masked softmax over the same
+    rows. A repeat-stacked pool is read at each layer of a ``lax.scan``,
+    whose counter is traced. float32 caches agree to summation order;
+    bfloat16 ones by the probabilities' rounding to the cache dtype before
+    the values' product (2^-8 relative on latents of order one). No row's
+    output is NaN: blocks past a row's last position are never read."""
+    lat, pos = _kernel_pool(dtype, stacked)
+    rows = (np.arange(len(FILLS)) if launch == "all"
+            else np.asarray([5, 0, 4, 2, 7, 1, 3, 6]))
+    q_pos = np.asarray(FILLS)[rows]
+    if launch == "permuted":
+        q_pos[-1] += 1                  # the pad lane's stale position
+    slot_idx = None if launch == "all" else jnp.asarray(rows, jnp.int32)
+    L = lat.shape[0] if stacked else 1
+    qa = jax.random.normal(jax.random.PRNGKey(8),
+                           (L, len(rows), KH, KW)).astype(dtype)
+
+    def attend(qa_l, layer):
+        return latent_decode_attention(
+            qa_l, lat, pos, jnp.asarray(q_pos, jnp.int32), slot_idx, layer,
+            r=KR, scale=KSCALE, block=KB)
+
+    if stacked:
+        got = jax.jit(lambda q: jax.lax.scan(
+            lambda c, xs: (c, attend(*xs)), 0,
+            (q, jnp.arange(L, dtype=jnp.int32)))[1])(qa)
+    else:
+        got = jax.jit(lambda q: attend(q[0], None))(qa)[None]
+    for layer in range(L):
+        lat_l = lat[layer] if stacked else lat
+        pos_l = pos[layer] if stacked else pos
+        want = _plain_attention(qa[layer], lat_l[rows], pos_l[rows], q_pos)
+        np.testing.assert_allclose(np.asarray(got[layer]), want, atol=atol)
 
 
 def test_chunked_no_drop_dispatch_is_row_local(monkeypatch):

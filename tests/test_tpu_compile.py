@@ -13,6 +13,7 @@ import dataclasses
 import json
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -191,3 +192,38 @@ def test_mla_serving_temporaries(one_chip):
             sds((B,))).compile().memory_analysis()
         assert mem.alias_size_in_bytes == pool_bytes
         assert mem.temp_size_in_bytes < limit, (B, S, mem.temp_size_in_bytes)
+
+
+def test_mla_decode_reads_the_pool_in_the_kernel(one_chip):
+    """DeepSeek-V2-Lite's decode at 32 slots x 4096 (published widths and
+    depth, a small vocab): both layer groups' latent attention is the
+    Mosaic kernel, under the ``attention`` scope, and nothing slices rows
+    of the latent pool (640 wide a position) out for XLA to read. The
+    chunk loop it replaced sliced 32 x 512 positions of a layer per step
+    and gathered the launched rows from them."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), vocab=512)
+    slots, cache_len = 32, 4096
+    runner = DecoderRunner(build_model(cfg), cfg, cache_len)
+    specs = runner.specs()
+    params = jax.eval_shape(
+        lambda: freeze_params(specs, init_params(specs, 0)))
+    pool = jax.eval_shape(lambda: runner.init_state(slots))
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    text = jax.jit(runner.decode, donate_argnums=(2,)).lower(
+        placed(params), sds((slots, 1)), placed(pool), sds((slots,)),
+        sds((slots,))).compile().as_text()
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln
+               and "latent_decode_attention" in ln]
+    assert len(kernels) == 2, len(kernels)
+    assert all("/attention/" in ln for ln in kernels)
+    pool_slices = re.findall(
+        r"= bf16\[([\d,]*,640)\]\S* dynamic-slice\(", text)
+    assert not pool_slices, pool_slices
